@@ -14,26 +14,36 @@ import sys
 
 
 def main() -> None:
+    # The measured microbenches run on 8 forced CPU host devices in their
+    # own process.  Start it before this process touches JAX, and pin it
+    # to the CPU, so that on an accelerator host the chip stays free for
+    # this process alone.
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
+    here = os.path.dirname(os.path.abspath(__file__))
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(here, "..", "src"), os.path.join(here, ".."),
+         env.get("PYTHONPATH", "")])
+    child = subprocess.Popen(
+        [sys.executable, os.path.join(here, "measured.py"), "--child"],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+
     from benchmarks import paper_tables, roofline
 
     rows = paper_tables.all_tables()
     for name, us, derived in rows:
         print(f"{name},{us:.2f},{derived}")
 
-    # measured multi-device microbenches (own process: 8 host devices)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    here = os.path.dirname(os.path.abspath(__file__))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(here, "..", "src"), os.path.join(here, ".."),
-         env.get("PYTHONPATH", "")])
-    proc = subprocess.run(
-        [sys.executable, os.path.join(here, "measured.py"), "--child"],
-        env=env, capture_output=True, text=True, timeout=1800)
-    if proc.returncode != 0:
-        print(f"measured_suite,0.00,ERROR: {proc.stderr[-400:]}")
+    try:
+        out, err = child.communicate(timeout=1800)
+    except subprocess.TimeoutExpired:
+        child.kill()
+        out, err = child.communicate()
+    if child.returncode != 0:
+        print(f"measured_suite,0.00,ERROR: {err[-400:]}")
     else:
-        for line in proc.stdout.splitlines():
+        for line in out.splitlines():
             if line.count(",") >= 2:
                 print(line)
 

@@ -26,10 +26,8 @@ def main() -> None:
     mesh = jax.make_mesh((1, 1), ("data", "model"))
     ctx = MeshCtx.from_mesh(mesh, mdmp_mode="auto")
     model = Model(cfg, ctx)
-    params = jax.tree.map(
-        lambda a, s: jax.device_put(np.asarray(a), s),
-        model.init(jax.random.key(0)),
-        infer_shardings(model.param_specs(), mesh))
+    params = model.init(jax.random.key(0),
+                        infer_shardings(model.param_specs(), mesh))
 
     engine = ServeEngine(model, mesh, params, slots=2, max_seq=64,
                          page_size=8, schedule="auto")

@@ -93,6 +93,34 @@ JUQUEEN_BGQ = HardwareModel(
 
 DEFAULT_HW = TPU_V5E
 
+#: The HardwareModel of each chip the managed runtime can price, keyed by
+#: ``jax.Device.device_kind``.  A TPU v5e reports "TPU v5 lite".
+HARDWARE_BY_KIND: dict[str, HardwareModel] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def hardware_for(device_kind: str) -> HardwareModel:
+    """The HardwareModel of ``device_kind``; a chip missing from
+    ``HARDWARE_BY_KIND`` is an error, never priced as another chip."""
+    try:
+        return HARDWARE_BY_KIND[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no HardwareModel for device kind {device_kind!r}; add it to "
+            f"cost_model.HARDWARE_BY_KIND") from None
+
+
+def device_hardware() -> HardwareModel:
+    """The HardwareModel of the device this process runs on.  A CPU
+    backend is a rehearsal of the production target, so it is priced as
+    ``DEFAULT_HW``; an accelerator is looked up by its device kind."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return DEFAULT_HW
+    return hardware_for(dev.device_kind)
+
 
 # ---------------------------------------------------------------------------
 # Collective cost primitives (ring algorithms, which is what managed.py emits)

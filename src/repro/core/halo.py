@@ -138,8 +138,8 @@ def _frozen_depths(axis_name: str, k: int, periodic: bool):
 def jacobi_step_aggregated(u: Array, f: Array, flo: Array, fhi: Array,
                            axis_name: str, k: int, *,
                            periodic: bool = False, engine: str = "jnp",
-                           blk_m: int = 256,
-                           interpret: bool = True) -> Array:
+                           blk_m: int | None = None,
+                           interpret: bool | None = None) -> Array:
     """k Jacobi sweeps for ONE k-row halo exchange (the aggregation knob).
 
     ``flo``/``fhi`` are the source term's k-row ghost slabs — f is
@@ -150,8 +150,11 @@ def jacobi_step_aggregated(u: Array, f: Array, flo: Array, fhi: Array,
     CPU-hosted benchmarks measure); engine="pallas" runs the VMEM-resident
     multi-sweep kernel (kernels/stencil.py) so the k x HBM-traffic saving
     is realised on TPU.  Both share ksweep_trapezoid, so they agree
-    bit-for-bit.
+    bit-for-bit.  ``blk_m`` (rows per kernel block) defaults to the largest
+    that fits VMEM; ``interpret`` defaults to the kernels' mode
+    (``kernels.ops._pallas_mode``): compiled on TPU, interpreted elsewhere.
     """
+    from repro.kernels.ops import _pallas_mode
     from repro.kernels.stencil import jacobi_ksweep_pallas, ksweep_trapezoid
 
     lo, hi = halo_exchange(u, axis_name, halo=k, periodic=periodic)
@@ -159,6 +162,8 @@ def jacobi_step_aggregated(u: Array, f: Array, flo: Array, fhi: Array,
     f_pad = jnp.concatenate([flo, f, fhi], axis=0)
     frozen_top, frozen_bot = _frozen_depths(axis_name, k, periodic)
     if engine == "pallas":
+        if interpret is None:
+            interpret = _pallas_mode() != "on"
         return jacobi_ksweep_pallas(u_pad, f_pad, k, frozen_top, frozen_bot,
                                     blk_m=blk_m, interpret=interpret)
     out = ksweep_trapezoid(u_pad.astype(jnp.float32),
@@ -170,7 +175,8 @@ def jacobi_step_aggregated(u: Array, f: Array, flo: Array, fhi: Array,
 def jacobi_solve(u0: Array, f: Array, axis_name: str, iters: int,
                  mode: str = "bulk", *, k: int = 1,
                  periodic: bool = False, engine: str = "jnp",
-                 blk_m: int = 256, interpret: bool = True) -> Array:
+                 blk_m: int | None = None,
+                 interpret: bool | None = None) -> Array:
     """Run ``iters`` Jacobi sweeps with the selected halo schedule.
 
     mode="bulk"        — paper Fig 2: 1-row exchange, then compute.
@@ -197,7 +203,8 @@ def jacobi_solve(u0: Array, f: Array, axis_name: str, iters: int,
 def _jacobi_solve(u0: Array, f: Array, axis_name: str, iters: int,
                   mode: str = "bulk", *, k: int = 1,
                   periodic: bool = False, engine: str = "jnp",
-                  blk_m: int = 256, interpret: bool = True) -> Array:
+                  blk_m: int | None = None,
+                  interpret: bool | None = None) -> Array:
     if mode == "aggregated":
         k = max(1, int(k))
         u = u0

@@ -114,20 +114,17 @@ def _collective_axes(eqn) -> tuple[str, ...]:
 def _source_of(eqn) -> str:
     """Repo-relative ``file:line`` of the user frame this eqn traces to
     (empty when source info is unavailable — e.g. synthetic jaxprs)."""
-    try:
-        from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is None:
-            return ""
-        fn = frame.file_name
-        for marker in ("src/repro/", "tests/", "benchmarks/", "examples/"):
-            i = fn.find(marker)
-            if i >= 0:
-                fn = fn[i:]
-                break
-        return f"{fn}:{frame.start_line}"
-    except Exception:
+    from jax._src import source_info_util
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
         return ""
+    fn = frame.file_name
+    for marker in ("src/repro/", "tests/", "benchmarks/", "examples/"):
+        i = fn.find(marker)
+        if i >= 0:
+            fn = fn[i:]
+            break
+    return f"{fn}:{frame.start_line}"
 
 
 def _walk(jaxpr: jcore.Jaxpr, tracked: dict[Any, str],

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import cost_model
-from repro.core.cost_model import HardwareModel, TPU_V5E
+from repro.core.cost_model import HardwareModel
 from repro.obs.tracer import dispatch_span
 
 Array = jax.Array
@@ -55,7 +55,8 @@ class MDMPConfig:
     """
     mode: str = "auto"                # auto | bulk | interleaved
     chunks: int | None = None         # override ring sub-chunking
-    hw: HardwareModel = TPU_V5E
+    hw: HardwareModel = dataclasses.field(
+        default_factory=cost_model.device_hardware)
     log_decisions: bool = True
     # quantized FSDP weight gathering (fp8 payload, bf16 master weights,
     # exact-dtype gradient reduce-scatter) — §Perf round 3

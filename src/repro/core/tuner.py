@@ -23,7 +23,7 @@ import re
 from typing import Any
 
 from repro.core import cost_model
-from repro.core.cost_model import HardwareModel, TPU_V5E
+from repro.core.cost_model import HardwareModel
 
 
 def call_site_key(op: str, shape: tuple, dtype: str, axis: str,
@@ -95,9 +95,9 @@ class ScheduleTuner:
     #: site (call_site_key always contains "|")
     PROGRAM_PLANS_KEY = "__program_plans__"
 
-    def __init__(self, hw: HardwareModel = TPU_V5E,
+    def __init__(self, hw: HardwareModel | None = None,
                  path: str | None = None):
-        self.hw = hw
+        self.hw = hw if hw is not None else cost_model.device_hardware()
         self.path = path
         self._entries: dict[str, TunerEntry] = {}
         self._program_plans: dict[str, dict] = {}

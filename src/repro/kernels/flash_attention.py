@@ -24,9 +24,13 @@ Two kernel entry points:
     distributed tests — merging partials over ANY kv split is exact up to
     float reduction order.
 
+Both kernels take the [B, S, H, hd] operands of the model code and run
+on a head-major [B, H, S, hd] copy, so every block is a (blk, hd) tile in
+the last two dims, as the TPU lowering requires.
+
 VMEM budget per step (bf16, blk_q = blk_kv = 512, hd = 256):
 q/k/v blocks 3 * 512*256*2 = 768 KB + f32 accumulators 512*256*4 = 512 KB
-— comfortably inside the ~128 MB/core VMEM with double buffering; block
+— inside v5e's 16 MiB default scoped VMEM with double buffering; block
 sizes are MXU-aligned multiples of 128 (tuned in ops.py).
 """
 
@@ -116,40 +120,36 @@ def flash_attention_pallas(q: Array, k: Array, v: Array, *,
         _flash_kernel, causal=causal, window=window, q_offset=q_offset,
         blk_q=blk_q, blk_kv=blk_kv, n_kv_blocks=nk, scale=scale)
 
+    # Head-major layout [B, H, S, hd]: the kernel's blocks are then
+    # (blk, hd) tiles in the last two dims, which Mosaic tiles as
+    # (sublane, lane).  A [B, S, H, hd] block would squeeze H in the
+    # second-minor position, which the TPU lowering refuses.
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     grid = (b, h, nq, nk)
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, blk_q, None, hd),
-                         lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
-            pl.BlockSpec((None, blk_kv, None, hd),
+            pl.BlockSpec((None, None, blk_q, hd),
+                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
+            pl.BlockSpec((None, None, blk_kv, hd),
                          lambda b_, h_, qi, ki, kvh=kvh, h=h:
-                         (b_, ki, h_ * kvh // h, 0)),
-            pl.BlockSpec((None, blk_kv, None, hd),
+                         (b_, h_ * kvh // h, ki, 0)),
+            pl.BlockSpec((None, None, blk_kv, hd),
                          lambda b_, h_, qi, ki, kvh=kvh, h=h:
-                         (b_, ki, h_ * kvh // h, 0)),
+                         (b_, h_ * kvh // h, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((None, blk_q, None, hd),
-                               lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((None, None, blk_q, hd),
+                               lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[
-            pl_scratch((blk_q, 1), jnp.float32),
-            pl_scratch((blk_q, 1), jnp.float32),
-            pl_scratch((blk_q, hd), jnp.float32),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
+            pltpu.VMEM((blk_q, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
-    return out
-
-
-def pl_scratch(shape, dtype):
-    from jax.experimental import pallas as pl_mod
-    try:
-        return pl_mod.VMEM(shape, dtype)          # newer API
-    except AttributeError:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, dtype)
+    )(qt, kt, vt)
+    return out.transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------
@@ -281,28 +281,30 @@ def flash_attention_carry_pallas(q: Array, k: Array, v: Array,
 
     offs = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(k_offset, jnp.int32)]).reshape(1, 2)
-    m4 = m[..., None]                     # [B, Sq, H, 1] (2-D VMEM blocks)
-    l4 = l[..., None]
+    # head-major layout, as in flash_attention_pallas; m and l get a
+    # trailing unit dim so their blocks are 2-D (blk_q, 1) tiles
+    qt, kt, vt, acc_t = (x.transpose(0, 2, 1, 3) for x in (q, k, v, acc))
+    m4 = m.transpose(0, 2, 1)[..., None]             # [B, H, Sq, 1]
+    l4 = l.transpose(0, 2, 1)[..., None]
 
     kernel = functools.partial(
         _flash_kernel_carry, causal=causal, window=window,
         blk_q=blk_q, blk_kv=blk_kv, n_kv_blocks=nk, scale=scale)
 
     grid = (b, h, nq, nk)
-    kv_spec = pl.BlockSpec((None, blk_kv, None, hd),
+    kv_spec = pl.BlockSpec((None, None, blk_kv, hd),
                            lambda b_, h_, qi, ki, kvh=kvh, h=h:
-                           (b_, ki, h_ * kvh // h, 0))
-    ml_spec = pl.BlockSpec((None, blk_q, None, 1),
-                           lambda b_, h_, qi, ki: (b_, qi, h_, 0))
-    acc_spec = pl.BlockSpec((None, blk_q, None, hd),
-                            lambda b_, h_, qi, ki: (b_, qi, h_, 0))
+                           (b_, h_ * kvh // h, ki, 0))
+    ml_spec = pl.BlockSpec((None, None, blk_q, 1),
+                           lambda b_, h_, qi, ki: (b_, h_, qi, 0))
+    acc_spec = pl.BlockSpec((None, None, blk_q, hd),
+                            lambda b_, h_, qi, ki: (b_, h_, qi, 0))
     m_new, l_new, acc_new = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),   # (q_offset, k_offset)
-            pl.BlockSpec((None, blk_q, None, hd),
-                         lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
+            acc_spec,
             kv_spec,
             kv_spec,
             ml_spec, ml_spec, acc_spec,
@@ -311,13 +313,14 @@ def flash_attention_carry_pallas(q: Array, k: Array, v: Array,
         out_shape=[
             jax.ShapeDtypeStruct(m4.shape, jnp.float32),
             jax.ShapeDtypeStruct(l4.shape, jnp.float32),
-            jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+            jax.ShapeDtypeStruct(acc_t.shape, jnp.float32),
         ],
         scratch_shapes=[
-            pl_scratch((blk_q, 1), jnp.float32),
-            pl_scratch((blk_q, 1), jnp.float32),
-            pl_scratch((blk_q, hd), jnp.float32),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
+            pltpu.VMEM((blk_q, 1), jnp.float32),
+            pltpu.VMEM((blk_q, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(offs, q, k, v, m4, l4, acc)
-    return m_new[..., 0], l_new[..., 0], acc_new
+    )(offs, qt, kt, vt, m4, l4, acc_t)
+    return (m_new[..., 0].transpose(0, 2, 1), l_new[..., 0].transpose(0, 2, 1),
+            acc_new.transpose(0, 2, 1, 3))

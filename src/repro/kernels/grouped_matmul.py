@@ -11,16 +11,19 @@ padded rows cost no FLOPs.
 
 Two engines with identical math (engine-matched on the shared pattern):
 
-  * ``grouped_expert_ffn`` with the Pallas path — grid (G, C/blk); the
-    valid counts ride in scalar-prefetch SMEM
+  * ``grouped_expert_ffn`` with the Pallas path — grid (G, C/blk, F/blk_f);
+    the valid counts ride in scalar-prefetch SMEM
     (``pltpu.PrefetchScalarGridSpec``, same mechanism as
     kernels/paged_attention.py's page tables); blocks whose first row is
     past ``valid[g]`` write zeros without touching the MXU, partial
     blocks mask rows before the dot so the zero rows contribute exact
-    zeros.
+    zeros.  d_ff is cut into tiles so the weight blocks fit VMEM, and
+    the down-projection accumulates over them in f32.
   * the jnp engine — rows masked by the same predicate, then the batched
-    einsum; bit-exact against the kernel (both contract D in f32 with
-    the same activation ops) including the padded capacity rows.
+    einsum; bit-exact against the kernel when d_ff is one tile (both
+    contract D in f32 with the same activation ops) including the padded
+    capacity rows; over several tiles only the f32 summation order of
+    the down-projection differs.
 
 The Pallas path carries a custom VJP whose backward recomputes through
 the jnp engine (the padded-row saving is a forward-schedule property;
@@ -104,15 +107,39 @@ def grouped_expert_ffn_jnp(h: Array, w1: Array, w1_gate: Array | None,
 # ---------------------------------------------------------------------------
 
 
-def _gemm_kernel(valid_ref, h_ref, *wo_refs, blk_c: int, mlp: str):
+#: VMEM the kernel's blocks may take (v5e's default scoped VMEM limit is
+#: 16 MiB; the rest is left to Mosaic's own temporaries)
+VMEM_BUDGET = 12 * 1024 * 1024
+
+
+def _ff_block(d: int, f: int, blk_c: int, n_w: int, itemsize: int) -> int:
+    """Largest d_ff tile whose double-buffered weight, activation and
+    accumulator blocks fit ``VMEM_BUDGET``: all of F, or a multiple of 128
+    that divides F (the lane width a weight block must be cut at)."""
+    fixed = 2 * 2 * blk_c * d * itemsize + blk_c * d * 4  # h, out, acc
+    for blk in [f] + [c for c in range(f - f % 128, 0, -128) if f % c == 0]:
+        if fixed + 2 * n_w * d * blk * itemsize <= VMEM_BUDGET:
+            return blk
+    raise ValueError(
+        f"grouped expert FFN: no d_ff tile of F={f} fits "
+        f"{VMEM_BUDGET} B of VMEM at D={d}, blk_c={blk_c}")
+
+
+def _gemm_kernel(valid_ref, h_ref, *refs, blk_c: int, mlp: str,
+                 n_f: int):
     if gated(mlp):
-        w1_ref, w1g_ref, w2_ref, o_ref = wo_refs
+        w1_ref, w1g_ref, w2_ref, o_ref, acc_ref = refs
     else:
-        w1_ref, w2_ref, o_ref = wo_refs
+        w1_ref, w2_ref, o_ref, acc_ref = refs
         w1g_ref = None
     g = pl.program_id(0)
     i = pl.program_id(1)
+    j = pl.program_id(2)
     v = valid_ref[g]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when(i * blk_c < v)
     def _compute():
@@ -128,15 +155,16 @@ def _gemm_kernel(valid_ref, h_ref, *wo_refs, blk_c: int, mlp: str):
             act = _act(mlp, u, gg)
         else:
             act = _act(mlp, u, None)
-        out = jax.lax.dot_general(act, w2_ref[...].astype(jnp.float32),
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        o_ref[...] = out.astype(o_ref.dtype)
+        # the activation is elementwise in d_ff, so each d_ff tile's
+        # down-projection is a partial sum of the output
+        acc_ref[...] += jax.lax.dot_general(
+            act, w2_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(i * blk_c >= v)
-    def _skip():
-        # fully padded capacity block: no MXU work, exact zeros out
-        o_ref[...] = jnp.zeros_like(o_ref)
+    # fully padded capacity blocks skip the MXU and write exact zeros
+    @pl.when(j == n_f - 1)
+    def _store():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 def grouped_expert_ffn_pallas(h: Array, w1: Array, w1_gate: Array | None,
@@ -145,32 +173,38 @@ def grouped_expert_ffn_pallas(h: Array, w1: Array, w1_gate: Array | None,
                               interpret: bool = False) -> Array:
     """The Pallas engine (see module docstring).  ``valid`` may be any
     integer/float array of per-group counts; blocks wholly past the count
-    are skipped via the scalar-prefetched predicate."""
+    are skipped via the scalar-prefetched predicate.  The grid walks
+    (group, capacity block, d_ff tile); d_ff is tiled so the weight
+    blocks fit VMEM (``_ff_block``)."""
     G, c, d = h.shape
-    e = w1.shape[0]
+    e, _, f = w1.shape
     gpe = G // e
     assert G % e == 0, (G, e)
     blk = blk_c if (c % blk_c == 0) else c
-    kernel = functools.partial(_gemm_kernel, blk_c=blk, mlp=mlp)
+    n_w = 3 if gated(mlp) else 2
+    blk_f = _ff_block(d, f, blk, n_w, h.dtype.itemsize)
+    n_f = f // blk_f
+    kernel = functools.partial(_gemm_kernel, blk_c=blk, mlp=mlp, n_f=n_f)
 
-    def w_spec(w):
-        return pl.BlockSpec((None,) + w.shape[1:],
-                            lambda g_, i, v: (g_ // gpe, 0, 0))
-
-    in_specs = [pl.BlockSpec((None, blk, d), lambda g_, i, v: (g_, i, 0)),
-                w_spec(w1)]
+    w_in_spec = pl.BlockSpec((None, d, blk_f),
+                             lambda g_, i, j, v: (g_ // gpe, 0, j))
+    in_specs = [pl.BlockSpec((None, blk, d), lambda g_, i, j, v: (g_, i, 0)),
+                w_in_spec]
     operands = [h, w1]
     if gated(mlp):
-        in_specs.append(w_spec(w1_gate))
+        in_specs.append(w_in_spec)
         operands.append(w1_gate)
-    in_specs.append(w_spec(w2))
+    in_specs.append(pl.BlockSpec((None, blk_f, d),
+                                 lambda g_, i, j, v: (g_ // gpe, j, 0)))
     operands.append(w2)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                       # valid counts
-        grid=(G, c // blk),
+        grid=(G, c // blk, n_f),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, blk, d), lambda g_, i, v: (g_, i, 0)),
+        out_specs=pl.BlockSpec((None, blk, d),
+                               lambda g_, i, j, v: (g_, i, 0)),
+        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,

@@ -40,6 +40,22 @@ def _pallas_mode() -> str:
     return "on" if jax.default_backend() == "tpu" else "off"
 
 
+def _kernel_tiles(mode: str, kernel: str, sq: int, skv: int, blk_q: int,
+                  blk_kv: int) -> bool:
+    """Whether the flash kernels run for these lengths.  With the kernels
+    on, a length the blocks do not tile is an error: the reference would
+    run in the kernel's place unseen."""
+    if mode not in ("on", "interpret"):
+        return False
+    if sq % min(blk_q, sq) == 0 and skv % min(blk_kv, skv) == 0:
+        return True
+    if mode == "on":
+        raise ValueError(
+            f"{kernel}: Sq={sq}, Skv={skv} do not tile into "
+            f"blk_q={blk_q}, blk_kv={blk_kv} blocks")
+    return False
+
+
 def flash_attention_applicable(q: Array, k: Array, v: Array) -> bool:
     """attend() fast-path predicate: True when any blockwise impl should
     replace the dense reference."""
@@ -71,8 +87,7 @@ def _flash_vjp(q, k, v, causal, window, q_offset, blk_q, blk_kv):
 def _flash_fwd_impl(q, k, v, causal, window, q_offset, blk_q, blk_kv):
     mode = _pallas_mode()
     sq, skv = q.shape[1], k.shape[1]
-    if mode in ("on", "interpret") and sq % min(blk_q, sq) == 0 \
-            and skv % min(blk_kv, skv) == 0:
+    if _kernel_tiles(mode, "flash_attention", sq, skv, blk_q, blk_kv):
         out = flash_attention_pallas(
             q, k, v, causal=causal, window=window, q_offset=q_offset,
             blk_q=blk_q, blk_kv=blk_kv, interpret=(mode == "interpret"))
@@ -343,9 +358,8 @@ def flash_attention_step(q: Array, k: Array, v: Array,
         carry = init_partials(b, sq, h, hd)
     m, l, acc = carry
     mode = _pallas_mode()
-    skv = k.shape[1]
-    if mode in ("on", "interpret") and sq % min(blk_q, sq) == 0 \
-            and skv % min(blk_kv, skv) == 0:
+    if _kernel_tiles(mode, "flash_attention_step", sq, k.shape[1], blk_q,
+                     blk_kv):
         return flash_attention_carry_pallas(
             q, k, v, m, l, acc, causal=causal, window=window,
             q_offset=q_offset, k_offset=k_offset, blk_q=blk_q,

@@ -42,8 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import (NEG_INF, finalize_partials,
-                                           init_partials, merge_partials,
-                                           pl_scratch)
+                                           init_partials, merge_partials)
 
 Array = jax.Array
 
@@ -64,18 +63,21 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[...].astype(jnp.float32)                # [H, hd]
-    k = k_ref[...].astype(jnp.float32)                # [page, KV, hd]
-    v = v_ref[...].astype(jnp.float32)
-    # GQA: expand kv heads to the q-head axis (head h reads kv head h//g)
-    ke = jnp.repeat(k, groups, axis=1)                # [page, H, hd]
-    ve = jnp.repeat(v, groups, axis=1)
+    # Flatten the page to 2-D rows (pos, kv head) -> pos * KV + kv so both
+    # products are plain 2-D MXU dots; a q head scores only the columns
+    # of its own kv head (GQA: head h reads kv head h // groups).
+    kvh = k_ref.shape[1]
+    k = k_ref[...].astype(jnp.float32).reshape(page * kvh, -1)
+    v = v_ref[...].astype(jnp.float32).reshape(page * kvh, -1)
     logits = jax.lax.dot_general(
-        q, ke.transpose(1, 0, 2), (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale   # [H, page]
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale   # [H, page * KV]
 
     valid_len = len_ref[b]
-    kpos = i * page + lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    mask = kpos < valid_len
+    col = lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    head = lax.broadcasted_iota(jnp.int32, logits.shape, 0)
+    kpos = i * page + col // kvh
+    mask = (kpos < valid_len) & (col % kvh == head // groups)
     if window > 0:
         mask &= kpos >= valid_len - window
     logits = jnp.where(mask, logits, NEG_INF)
@@ -89,7 +91,7 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     p = jnp.where(mask, p, 0.0)
     l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, ve.transpose(1, 0, 2), (((1,), (1,)), ((0,), (0,))),
+        p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)           # [H, hd]
     m_scr[...] = m_new
     l_scr[...] = l_new
@@ -131,9 +133,9 @@ def paged_attention_pallas(q: Array, k_pages: Array, v_pages: Array,
         out_specs=pl.BlockSpec((None, h, hd),
                                lambda b_, i, tbl, ln: (b_, 0, 0)),
         scratch_shapes=[
-            pl_scratch((h, 1), jnp.float32),
-            pl_scratch((h, 1), jnp.float32),
-            pl_scratch((h, hd), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, hd), jnp.float32),
         ],
     )
     return pl.pallas_call(

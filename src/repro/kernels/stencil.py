@@ -77,8 +77,18 @@ def jacobi_step_pallas(u: Array, f: Array, *, blk_m: int = 256,
 # ---------------------------------------------------------------------------
 
 
+#: Scoped VMEM the k-sweep kernel asks Mosaic for (a v5e TensorCore has
+#: 128 MiB; the default scoped limit is 16 MiB), and the part of it the
+#: row-block sizing may fill with its estimate of blocks and temporaries.
+VMEM_LIMIT = 96 * 1024 * 1024
+VMEM_BUDGET = 48 * 1024 * 1024
+#: f32 tile-sized temporaries a sweep keeps live (tile, f, four shifted
+#: neighbours, the update, the select)
+_SWEEP_TEMPS = 8
+
+
 def ksweep_trapezoid(tile: Array, f_tile: Array, k: int, frozen_top,
-                     frozen_bot) -> Array:
+                     frozen_bot, roll=jnp.roll) -> Array:
     """Apply ``k`` masked Jacobi sweeps to a halo-padded row tile.
 
     tile, f_tile: [T, N] float32.  Columns 0 and N-1 are Dirichlet (never
@@ -91,43 +101,77 @@ def ksweep_trapezoid(tile: Array, f_tile: Array, k: int, frozen_top,
 
     Validity contract (the trapezoid): if tile rows [0, T) hold iteration-0
     values, then after this call rows [k, T-k) hold iteration-k values
-    (frozen edges do not shrink).  Shared verbatim by the Pallas kernel and
-    the jnp deep-halo path so both produce bit-identical schedules.
+    (frozen edges do not shrink).  The neighbours are whole-tile rotations
+    (``roll`` is ``jnp.roll`` here and ``pltpu.roll`` in the kernel, which
+    keeps every vector op tile-aligned); the wrapped rows and columns are
+    exactly the ones the mask never updates.  Shared by the Pallas kernel
+    and the jnp deep-halo path, and summed in the order of the bulk
+    five-point update, so all three agree bit-for-bit.
     """
-    t_rows = tile.shape[0]
-    rows = lax.broadcasted_iota(jnp.int32, (t_rows, 1), 0)
-    upd = (rows >= frozen_top) & (rows < t_rows - frozen_bot)
-    for _ in range(k):                            # k is static: unrolled
-        new = 0.25 * (tile[:-2, 1:-1] + tile[2:, 1:-1]
-                      + tile[1:-1, :-2] + tile[1:-1, 2:]
-                      - f_tile[1:-1, 1:-1])
-        mid = jnp.concatenate([tile[1:-1, :1], new, tile[1:-1, -1:]], axis=1)
-        swept = jnp.concatenate([tile[:1], mid, tile[-1:]], axis=0)
-        tile = jnp.where(upd, swept, tile)
-    return tile
+    t_rows, n = tile.shape
+    rows = lax.broadcasted_iota(jnp.int32, (t_rows, n), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (t_rows, n), 1)
+    upd = ((rows >= jnp.maximum(frozen_top, 1))
+           & (rows < t_rows - jnp.maximum(frozen_bot, 1))
+           & (cols >= 1) & (cols < n - 1))
+
+    def sweep(_, tile):
+        new = 0.25 * (roll(tile, 1, 0) + roll(tile, t_rows - 1, 0)
+                      + roll(tile, 1, 1) + roll(tile, n - 1, 1) - f_tile)
+        return jnp.where(upd, new, tile)
+
+    return lax.fori_loop(0, k, sweep, tile)
 
 
-def _jacobi_multistep_kernel(frozen_ref, utop_ref, umid_ref, ubot_ref,
-                             ftop_ref, fmid_ref, fbot_ref, o_ref, *, k: int):
-    """One grid step: assemble the (blk_m + 2k, N) apron tile in VMEM from
-    the three disjoint row-block inputs, run k sweeps, write the blk_m
-    center rows.  Frozen-edge depths apply only in the first/last block."""
-    i = pl.program_id(0)
-    nb = pl.num_programs(0)
-    frozen_top = jnp.where(i == 0, frozen_ref[0, 0], 0)
-    frozen_bot = jnp.where(i == nb - 1, frozen_ref[0, 1], 0)
-    tile = jnp.concatenate(
-        [utop_ref[...], umid_ref[...], ubot_ref[...]], axis=0
-    ).astype(jnp.float32)
-    f_tile = jnp.concatenate(
-        [ftop_ref[...], fmid_ref[...], fbot_ref[...]], axis=0
-    ).astype(jnp.float32)
-    out = ksweep_trapezoid(tile, f_tile, k, frozen_top, frozen_bot)
-    o_ref[...] = out[k:-k].astype(o_ref.dtype)
+def _jacobi_multistep_kernel(frozen_ref, u_ref, ulow_ref, f_ref, flow_ref,
+                             o_ref, *, k: int, apron: int, rows: int):
+    """One grid step: the (blk_m + 2*apron)-row tile is a blk_m-row block
+    plus the 2*apron rows below it; run k sweeps in VMEM and write the
+    blk_m center rows.  The frozen depths count rows of the whole padded
+    array (``rows`` of them) and are moved to this tile's coordinates."""
+    blk_m = o_ref.shape[0]
+    t_rows = blk_m + 2 * apron
+    top = pl.program_id(0) * blk_m                # tile row 0, globally
+    frozen_top = frozen_ref[0, 0] - top
+    frozen_bot = frozen_ref[0, 1] - (rows - top - t_rows)
+    tile = jnp.concatenate([u_ref[...], ulow_ref[...]],
+                           axis=0).astype(jnp.float32)
+    f_tile = jnp.concatenate([f_ref[...], flow_ref[...]],
+                             axis=0).astype(jnp.float32)
+    out = ksweep_trapezoid(tile, f_tile, k, frozen_top, frozen_bot,
+                           roll=pltpu.roll)
+    o_ref[...] = out[apron:apron + blk_m].astype(o_ref.dtype)
+
+
+def _row_block(m: int, n: int, apron: int, itemsize: int,
+               blk_m: int | None) -> int:
+    """Rows per grid step: a multiple of 2*apron (the lower-apron block
+    must land on its own block grid).  Given ``blk_m`` is checked;
+    otherwise the largest such divisor of the apron-rounded height whose
+    blocks and sweep temporaries fit ``VMEM_BUDGET``."""
+    step = 2 * apron
+    if blk_m is not None:
+        if blk_m % step:
+            raise ValueError(f"blk_m={blk_m} must be a multiple of "
+                             f"2 * apron = {step}")
+        return blk_m
+    rows = -(-m // step) * step
+
+    def vmem(b):
+        io = 2 * (3 * b + 2 * step) * n * itemsize   # double-buffered
+        return io + _SWEEP_TEMPS * (b + step) * n * 4
+
+    fits = [b for b in range(step, rows + 1, step)
+            if rows % b == 0 and vmem(b) <= VMEM_BUDGET]
+    if not fits:
+        raise ValueError(
+            f"k-sweep stencil: even a {step}-row block of {n} columns "
+            f"needs {vmem(step)} B of VMEM (budget {VMEM_BUDGET} B)")
+    return max(fits)
 
 
 def jacobi_ksweep_pallas(u_pad: Array, f_pad: Array, k: int, frozen_top,
-                         frozen_bot, *, blk_m: int = 256,
+                         frozen_bot, *, blk_m: int | None = None,
                          interpret: bool = False) -> Array:
     """k Jacobi sweeps over the center rows of a k-halo-padded block.
 
@@ -140,50 +184,55 @@ def jacobi_ksweep_pallas(u_pad: Array, f_pad: Array, k: int, frozen_top,
     leading/trailing PADDED rows — pass k at a non-periodic physical edge
     so the zero ghost slab behaves as a constant boundary, 0 elsewhere.
 
-    Each grid step streams blk_m + 2k rows of u and f HBM->VMEM, runs all
-    k sweeps on the VMEM-resident tile, and writes blk_m rows back: the
+    Each grid step streams blk_m + 2*apron rows of u and f HBM->VMEM, runs
+    all k sweeps on the VMEM-resident tile, and writes blk_m rows back: the
     HBM traffic per sweep drops ~k x vs. calling jacobi_step_pallas k
-    times, which is the whole point of the temporal blocking.
+    times, which is the whole point of the temporal blocking.  The apron
+    is k rounded up to the sublane tile (8 rows of f32), so every block
+    is tile-aligned; the extra rows, and rows padding m up to a whole
+    number of blocks, are zeros pinned by the frozen depths.  ``blk_m``
+    defaults to the largest row block that fits the VMEM budget.
     """
-    assert k >= 1
+    if k < 1:
+        raise ValueError(f"k={k} must be >= 1")
     mp, n = u_pad.shape
     m = mp - 2 * k
-    assert m >= 1, (mp, k)
-    if m % blk_m != 0 or blk_m % k != 0 or blk_m < k:
-        blk_m = m                                 # single row-tile fallback
-    grid = (m // blk_m,)
-
-    # Three disjoint row-block views assemble each (blk_m + 2k)-row apron
-    # tile: top apron rows [i*blk_m, i*blk_m + k), center rows
-    # [i*blk_m + k, i*blk_m + k + blk_m), bottom apron rows
-    # [i*blk_m + k + blk_m, i*blk_m + 2k + blk_m) — all in u_pad coords.
-    halo_stride = max(blk_m // k, 1)              # block-index stride of the
-    top_spec = pl.BlockSpec((k, n), lambda i: (i * halo_stride, 0))
-    mid_spec = pl.BlockSpec((blk_m, n), lambda i: (i, 0))
-    frozen = jnp.stack([jnp.asarray(frozen_top, jnp.int32),
-                        jnp.asarray(frozen_bot, jnp.int32)]).reshape(1, 2)
-    kernel = functools.partial(_jacobi_multistep_kernel, k=k)
-    return pl.pallas_call(
+    if m < 1:
+        raise ValueError(f"no center rows: {mp} rows with a {k}-row apron")
+    sublanes = 8 * 4 // u_pad.dtype.itemsize
+    apron = -(-k // sublanes) * sublanes
+    blk_m = _row_block(m, n, apron, u_pad.dtype.itemsize, blk_m)
+    m_t = -(-m // blk_m) * blk_m
+    top, bot = apron - k, apron - k + m_t - m
+    if top or bot:
+        u_pad = jnp.pad(u_pad, ((top, bot), (0, 0)))
+        f_pad = jnp.pad(f_pad, ((top, bot), (0, 0)))
+    frozen = jnp.stack([jnp.asarray(frozen_top, jnp.int32) + top,
+                        jnp.asarray(frozen_bot, jnp.int32) + bot]
+                       ).reshape(1, 2)
+    low = blk_m // (2 * apron)                    # lower-apron block stride
+    blk_spec = pl.BlockSpec((blk_m, n), lambda i: (i, 0))
+    low_spec = pl.BlockSpec((2 * apron, n), lambda i: ((i + 1) * low, 0))
+    kernel = functools.partial(_jacobi_multistep_kernel, k=k, apron=apron,
+                               rows=m_t + 2 * apron)
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(m_t // blk_m,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),       # frozen depths
-            top_spec,                                    # u top apron
-            mid_spec,                                    # u center
-            top_spec,                                    # u bottom apron
-            top_spec,                                    # f top apron
-            mid_spec,                                    # f center
-            top_spec,                                    # f bottom apron
+            blk_spec, low_spec,                          # u
+            blk_spec, low_spec,                          # f
         ],
-        out_specs=mid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), u_pad.dtype),
+        out_specs=blk_spec,
+        out_shape=jax.ShapeDtypeStruct((m_t, n), u_pad.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(frozen, u_pad, u_pad[k:-k], u_pad[blk_m + k:],
-      f_pad, f_pad[k:-k], f_pad[blk_m + k:])
+    )(frozen, u_pad, u_pad, f_pad, f_pad)
+    return out if m_t == m else out[:m]
 
 
 def jacobi_multistep_pallas(u: Array, f: Array, *, k: int,
-                            blk_m: int = 256,
+                            blk_m: int | None = None,
                             interpret: bool = False) -> Array:
     """``k`` Jacobi sweeps on the interior of ``u`` ([M, N]) in ONE HBM
     round-trip — temporally-blocked equivalent of calling

@@ -28,12 +28,26 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.configs.base import ModelConfig
 from repro.core import managed
 from repro.core.faults import FaultPlan
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import Model
 from repro.parallel.sharding import MeshCtx, infer_shardings
 from repro.serve.engine import ServeEngine
 from repro.serve.scheduler import RequestRejected
+
+
+def build_engine(cfg: ModelConfig, *, mdmp_mode: str = "auto",
+                 seed: int = 0, **engine_kw) -> ServeEngine:
+    """The serving engine over every local device: a (data, model) mesh
+    with all devices on data, parameters drawn from ``seed`` straight
+    into their shardings, and ``engine_kw`` passed to ``ServeEngine``."""
+    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    model = Model(cfg, MeshCtx.from_mesh(mesh, mdmp_mode=mdmp_mode))
+    params = model.init(jax.random.key(seed),
+                        infer_shardings(model.param_specs(), mesh))
+    return ServeEngine(model, mesh, params, **engine_kw)
 
 
 def main() -> None:
@@ -82,6 +96,7 @@ def main() -> None:
                          "print the predicted-vs-measured calibration "
                          "report, and embed the ledger in the file")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.trace:
         # install before the engine resolves anything so admission,
@@ -91,23 +106,15 @@ def main() -> None:
 
     cfg = (configs.get_reduced(args.arch) if args.reduced
            else configs.get_config(args.arch))
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
-    ctx = MeshCtx.from_mesh(mesh, mdmp_mode=args.mdmp_mode)
-    model = Model(cfg, ctx)
-    params = jax.tree.map(
-        lambda a, s: jax.device_put(np.asarray(a), s),
-        model.init(jax.random.key(0)),
-        infer_shardings(model.param_specs(), mesh))
-
     plan = (FaultPlan.parse(args.fault_plan) if args.fault_plan
             else None)
-    engine = ServeEngine(model, mesh, params, slots=args.slots,
-                         max_seq=args.max_seq, page_size=args.page_size,
-                         n_pages=args.pages, schedule=args.schedule,
-                         chunk=args.chunk, fault_plan=plan,
-                         preempt=args.preempt,
-                         slo_ttft_s=args.slo_ttft,
-                         max_queue=args.max_queue)
+    engine = build_engine(cfg, mdmp_mode=args.mdmp_mode, slots=args.slots,
+                          max_seq=args.max_seq, page_size=args.page_size,
+                          n_pages=args.pages, schedule=args.schedule,
+                          chunk=args.chunk, fault_plan=plan,
+                          preempt=args.preempt,
+                          slo_ttft_s=args.slo_ttft,
+                          max_queue=args.max_queue)
     prog = None
     if args.plan != "local" or args.verify != "off":
         # Lower the serving comm set once — the whole-program planner

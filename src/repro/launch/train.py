@@ -15,6 +15,7 @@ import jax
 
 from repro import configs
 from repro.data.pipeline import DataConfig, SyntheticLMData
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import Model
 from repro.optim.adamw import AdamWConfig
 from repro.parallel.sharding import MeshCtx
@@ -82,6 +83,7 @@ def main() -> None:
                          "predicted-vs-measured calibration report, and "
                          "embed the calibration ledger in the file")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.trace:
         # install before anything resolves so planner/lint/step spans

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
+from functools import lru_cache
 from typing import Any
 
 import jax
@@ -30,6 +30,26 @@ from repro.parallel.sharding import (LOGICAL_RULES, MeshCtx, ParamSpec,
 
 Array = jax.Array
 PS = ParamSpec
+
+
+#: SSM leaves that start from a constant instead of zero (A in [1, e],
+#: dt_bias ~ softplus-inv, unit skip)
+_CONST_INIT = {"a_log": 0.5, "dt_bias": 0.1, "d_skip": 1.0}
+
+
+@lru_cache(maxsize=256)
+def _leaf_init(shape: tuple[int, ...], logical: tuple[str, ...], dtype,
+               const: float, sharding):
+    """Jitted initializer of one parameter leaf, placed by ``sharding``;
+    cached so models of one shape compile their leaves once."""
+    def one(k):
+        if len([l for l in logical if l != "layers"]) <= 1:
+            # norm scales / per-head scalars: constants
+            return jnp.full(shape, const, dtype)
+        scale = 1.0 / math.sqrt(max(shape[-2], 1))        # fan-in
+        return (jax.random.normal(k, shape, jnp.float32)
+                * scale).astype(dtype)
+    return jax.jit(one, out_shardings=sharding)
 
 
 def _gated_mult(cfg: ModelConfig) -> int:
@@ -172,47 +192,29 @@ class Model:
         return self.cfg.family != "hybrid"
 
     # ------------------------------------------------------------------
-    # Init (global arrays — for CPU-scale configs; dry-run uses specs only)
+    # Init: seeded random weights, built straight into their shardings
     # ------------------------------------------------------------------
 
-    def init(self, key: Array) -> dict:
+    def init(self, key: Array, shardings: Any = None) -> dict:
+        """Seeded random parameters.  Each leaf is made by its own jitted
+        program whose output lands in its sharding (``shardings``: a tree
+        of ``jax.sharding.Sharding`` matching ``param_specs()``; default
+        placement when None), so no full copy of the weights is ever held
+        on one device or on the host, and the float32 draw of a leaf is a
+        temporary of that leaf's program alone."""
         cfg = self.cfg
-        specs = self.param_specs()
-        leaves, treedef = jax.tree.flatten(
-            specs, is_leaf=lambda x: isinstance(x, PS))
-        keys = jax.random.split(key, len(leaves))
+        flat, treedef = jax.tree_util.tree_flatten_with_path(
+            self.param_specs(), is_leaf=lambda x: isinstance(x, PS))
+        keys = jax.random.split(key, len(flat))
+        shards = (treedef.flatten_up_to(shardings) if shardings is not None
+                  else [None] * len(flat))
         dtype = jnp.dtype(cfg.dtype)
-
-        def one(k, spec: PS):
-            shape = spec.shape
-            non_layer = [l for l in spec.logical if l != "layers"]
-            if len(non_layer) <= 1:
-                # norm scales / per-head scalars: zeros (fixed up below)
-                return jnp.zeros(shape, dtype)
-            fan_in = shape[-2]
-            scale = 1.0 / math.sqrt(max(fan_in, 1))
-            return (jax.random.normal(k, shape, jnp.float32)
-                    * scale).astype(dtype)
-
-        out = jax.tree.unflatten(treedef, [one(k, s) for k, s in
-                                           zip(keys, leaves)])
-        # SSM-specific non-zero inits (A in [1, e], dt_bias ~ softplus-inv)
-        def fix_ssm(tree):
-            if isinstance(tree, dict):
-                for name, vdict in tree.items():
-                    if isinstance(vdict, dict):
-                        fix_ssm(vdict)
-                if "a_log" in tree:
-                    tree["a_log"] = jnp.zeros_like(tree["a_log"]) + \
-                        jnp.asarray(0.5, dtype)
-                    tree["dt_bias"] = jnp.zeros_like(tree["dt_bias"]) + \
-                        jnp.asarray(0.1, dtype)
-                    tree["d_skip"] = jnp.ones_like(tree["d_skip"])
-            elif isinstance(tree, list):
-                for t in tree:
-                    fix_ssm(t)
-        fix_ssm(out)
-        return out
+        out = []
+        for k, (path, spec), sh in zip(keys, flat, shards):
+            const = _CONST_INIT.get(getattr(path[-1], "key", None), 0.0)
+            out.append(_leaf_init(spec.shape, spec.logical, dtype, const,
+                                  sh)(k))
+        return jax.tree.unflatten(treedef, out)
 
     # ------------------------------------------------------------------
     # Forward (SP flow)

@@ -36,7 +36,7 @@ class AdamWConfig:
 
 def adamw_init(params: Any, cfg: AdamWConfig) -> dict:
     dt = jnp.dtype(cfg.moment_dtype)
-    zeros = lambda p: jnp.zeros(p.shape, dt)
+    zeros = lambda p: jnp.zeros_like(p, dtype=dt)   # keeps p's sharding
     return {
         "mu": jax.tree.map(zeros, params),
         "nu": jax.tree.map(zeros, params),

@@ -30,25 +30,12 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(fn: Callable, mesh: Mesh, in_specs, out_specs,
-                     check_vma: bool = False) -> Callable:
-    """Version-portable ``shard_map``: the single place the repo touches the
-    API.  jax >= 0.5 exposes ``jax.shard_map`` (with ``check_vma``); on
-    0.4.x the alias does not exist, so fall back to
-    ``jax.experimental.shard_map.shard_map`` (whose equivalent knob is
-    ``check_rep``).  Every call site routes through here (via ``smap``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def smap(fn: Callable, mesh: Mesh, in_specs, out_specs) -> Callable:
-    """shard_map with VMA checking off (ring collectives produce values the
-    replication checker cannot infer; correctness is covered by tests)."""
-    return shard_map_compat(fn, mesh, in_specs, out_specs, check_vma=False)
+    """``jax.shard_map`` with VMA checking off (ring collectives produce
+    values the replication checker cannot infer; correctness is covered by
+    tests).  Every shard_map in the repo goes through here."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def pad_to_multiple(n: int, m: int) -> int:

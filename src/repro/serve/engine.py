@@ -187,16 +187,22 @@ class ServeEngine:
             self._steps[chunk] = fn
         return fn
 
+    def _idle_args(self, chunk: int) -> tuple:
+        """Quantum arguments of a zero-step quantum (touches no state)."""
+        zeros = jnp.asarray(np.zeros(self.slots, np.int32))
+        return (self.params, self.cache, jnp.asarray(self.pt.table),
+                jnp.zeros((self.slots, chunk), jnp.int32),
+                jnp.asarray(np.ones(self.slots, np.int32)), zeros, zeros)
+
     def warmup(self, chunk: int) -> None:
-        """Compile the quantum function outside the measured loop (a
-        zero-step quantum touches no state)."""
-        zeros = np.zeros(self.slots, np.int32)
-        out, self.cache = self._step_fn(chunk)(
-            self.params, self.cache, jnp.asarray(self.pt.table),
-            jnp.zeros((self.slots, chunk), jnp.int32),
-            jnp.asarray(np.ones(self.slots, np.int32)),
-            jnp.asarray(zeros), jnp.asarray(zeros))
+        """Compile the quantum function outside the measured loop."""
+        out, self.cache = self._step_fn(chunk)(*self._idle_args(chunk))
         jax.block_until_ready(out)
+
+    def lower_quantum(self, chunk: int) -> Any:
+        """The quantum of ``chunk`` steps, lowered (``jax.stages.Lowered``)
+        for inspecting what it compiles to."""
+        return self._step_fn(chunk).lower(*self._idle_args(chunk))
 
     # -- queue ---------------------------------------------------------------
 
@@ -311,9 +317,12 @@ class ServeEngine:
                 row_bytes = (int(np.prod(leaf.shape)) // leaf.shape[ax]
                              * leaf.dtype.itemsize)
                 rpc = self._swap_chunk_rows(row_bytes)
-                parts = [np.asarray(jnp.take(leaf,
-                                             jnp.asarray(ids[i:i + rpc]),
-                                             axis=ax))
+                # the pool's page axis is sharded: name the (replicated)
+                # sharding of the gathered rows, which jax cannot infer
+                rep = NamedSharding(self.mesh, P())
+                pre = (slice(None),) * ax
+                parts = [np.asarray(leaf.at[pre + (ids[i:i + rpc],)].get(
+                             out_sharding=rep))
                          for i in range(0, len(ids), rpc)]
                 empty = leaf.shape[:ax] + (0,) + leaf.shape[ax + 1:]
                 rows = (np.concatenate(parts, axis=ax) if parts else
@@ -359,10 +368,11 @@ class ServeEngine:
                              * leaf.dtype.itemsize)
                 rpc = self._swap_chunk_rows(row_bytes)
                 pre = (slice(None),) * ax
+                home = NamedSharding(self.mesh, ps)
                 for i in range(0, len(new_ids), rpc):
                     leaf = leaf.at[pre + (new_ids[i:i + rpc],)].set(
-                        jnp.asarray(rows[pre + (slice(i, i + rpc),)]))
-                leaf = jax.device_put(leaf, NamedSharding(self.mesh, ps))
+                        jnp.asarray(rows[pre + (slice(i, i + rpc),)]),
+                        out_sharding=home)
                 out_leaves.append(leaf)
                 nbytes += rows.nbytes
             self.cache = jax.tree.unflatten(treedef, out_leaves)

@@ -300,7 +300,7 @@ def build_train_step(model: Model, opt_cfg: AdamWConfig, mesh: Mesh, *,
 @dataclasses.dataclass
 class TrainLoopConfig:
     total_steps: int = 100
-    ckpt_every: int = 25
+    ckpt_every: int = 25                # 0: take no checkpoints
     ckpt_dir: str = "/tmp/repro_ckpt"
     keep: int = 3
     log_every: int = 10
@@ -377,9 +377,12 @@ class TrainLoop:
     # -- state management ----------------------------------------------------
 
     def init_state(self, seed: int = 0) -> tuple[Any, Any, int]:
-        params = self.model.init(jax.random.key(seed))
-        params = jax.tree.map(jax.device_put, params, self.param_shardings)
+        params = self.model.init(jax.random.key(seed), self.param_shardings)
         opt = adamw_init(params, self.opt_cfg)
+        # the step returns its counter replicated over the mesh; start it
+        # there, or the second step compiles the whole program again
+        mesh = jax.tree.leaves(self.param_shardings)[0].mesh
+        opt["step"] = jax.device_put(opt["step"], NamedSharding(mesh, P()))
         return params, opt, 0
 
     def resume_or_init(self, seed: int = 0) -> tuple[Any, Any, int]:
@@ -529,8 +532,8 @@ class TrainLoop:
             if cfg.managed_cadence and self.recal.should_retune():
                 self._resolve_cadence(self.recal.value, snapshot_bytes)
             step += 1
-            if step - last_saved >= self.ckpt_interval \
-                    or step == cfg.total_steps:
+            if cfg.ckpt_every > 0 and (step - last_saved >= self.ckpt_interval
+                                       or step == cfg.total_steps):
                 # scale = the train seconds this cadence amortizes one
                 # checkpoint over, so dur/scale is the measured overhead
                 # fraction — the unit resolve_checkpoint predicts

@@ -157,7 +157,7 @@ def test_all_reduce_scalar_fallback_logged(mesh8):
     out = run(mesh8,
               lambda a: managed.managed_all_reduce(a[0, 0], "x",
                                                    mode="interleaved"),
-              (P(None),), P(None), jnp.ones((1, 1), jnp.float32))
+              (P(None),), P(), jnp.ones((1, 1), jnp.float32))
     np.testing.assert_allclose(out, float(N))
     recs = [r for r in managed.decision_log() if r.op == "all_reduce"]
     assert recs and all(r.mode == "bulk" for r in recs)

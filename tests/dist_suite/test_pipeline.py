@@ -95,7 +95,7 @@ def test_training_schedules_match_sequential_oracle(mesh8):
                 jax.ShapeDtypeStruct((b, d), np.float32), sched, "x")
 
         loss, grads = jax.jit(smap(run, mesh8, in_specs=(P(None),),
-                                   out_specs=(P(None), P(None))))(ws)
+                                   out_specs=(P(), P(None))))(ws)
         np.testing.assert_allclose(float(loss), float(want_loss),
                                    rtol=1e-5)
         np.testing.assert_allclose(np.asarray(grads), np.asarray(want_g),

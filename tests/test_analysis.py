@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import analysis
@@ -24,6 +23,7 @@ from repro.analysis.graph import (BufferAccess, CommGraph, InFlight,
 from repro.core import cost_model, instrument, managed
 from repro.core.managed import _ring_perm
 from repro.core.region import CommRegion, UnknownAxisError
+from repro.parallel import smap
 from repro.plan import CommOp, lower_collectives, train_geometry
 
 CORPUS = os.path.join(os.path.dirname(__file__), "lint_corpus")
@@ -85,8 +85,7 @@ def test_scan_body_ppermute_extracted_once_with_trips():
         out, sums = lax.scan(step, a, None, length=LEN)
         return out.sum() + sums.sum()
 
-    f = shard_map(body, mesh=mesh, in_specs=(P("x"),), out_specs=P(),
-                  check_rep=False)
+    f = smap(body, mesh, in_specs=(P("x"),), out_specs=P())
     rep = instrument.analyze_region(f, jnp.ones((4, 2), jnp.float32))
     perms = [c for c in rep.collectives if c.primitive == "ppermute"]
     assert len(perms) == 1              # one logical site
@@ -117,8 +116,7 @@ def test_scan_carry_binders_align_with_closure_consts():
         out, _ = lax.scan(step, a, None, length=3)
         return out.sum()
 
-    f = shard_map(body, mesh=mesh, in_specs=(P(None),), out_specs=P(),
-                  check_rep=False)
+    f = smap(body, mesh, in_specs=(P(None),), out_specs=P())
     rep = instrument.analyze_region(f, jnp.ones((4,), jnp.float32))
     perms = [c for c in rep.collectives if c.primitive == "ppermute"]
     assert len(perms) == 1 and perms[0].trips == 3
@@ -465,8 +463,7 @@ def test_graph_from_region_trace_and_plan():
         s = lax.psum(b, "y")                 # never declared
         return g.sum() + s.sum()
 
-    f = shard_map(body, mesh=mesh, in_specs=(P("x"), P(None)),
-                  out_specs=P(), check_rep=False)
+    f = smap(body, mesh, in_specs=(P("x"), P(None)), out_specs=P())
     rep = instrument.analyze_region(f, jnp.ones((4, 2), jnp.float32),
                                     jnp.ones((3,), jnp.float32))
     graph = analysis.from_ops(

@@ -202,3 +202,13 @@ def test_halo_terms_structure():
     assert c1 - c8 == pytest.approx(2 * hw.alpha_s * (1 - 1 / 8))
     assert m8 < m1                     # k x fewer HBM round-trips
     assert f8 > f1                     # ghost trapezoid is extra compute
+
+
+def test_hardware_by_device_kind():
+    """The managed layer prices a chip by its device kind; a kind missing
+    from the table is an error, not another chip's constants."""
+    assert cm.hardware_for("TPU v5 lite") is cm.TPU_V5E
+    with pytest.raises(KeyError, match="no HardwareModel"):
+        cm.hardware_for("TPU v9 imaginary")
+    # the CPU backend rehearses the production target
+    assert cm.device_hardware() is cm.DEFAULT_HW

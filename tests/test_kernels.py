@@ -177,7 +177,7 @@ def test_jacobi_pallas_vs_ref(m, n, bm, bn, dtype):
 
 
 @pytest.mark.parametrize("m,n,bm", [
-    (66, 130, 64),        # single-tile fallback (66 % 64 != 0)
+    (66, 130, 64),        # 66 rows padded up to two 64-row blocks
     (256, 130, 64),       # 4-block grid
     (128, 258, 16),       # 8-block grid, tiny tiles
 ])
@@ -244,3 +244,26 @@ def test_jacobi_converges():
     for _ in range(50):
         u = jacobi_step_pallas(u, f, blk_m=64, blk_n=64, interpret=True)
     assert residual(u) < r0
+
+
+def test_flash_untileable_length_raises_when_kernels_on(monkeypatch):
+    """With the kernels on, a length the blocks do not tile must not run
+    the reference in the kernel's place."""
+    monkeypatch.setenv("REPRO_PALLAS", "on")
+    rng = np.random.default_rng(10)
+    q = _rand(rng, (1, 200, 2, 32), jnp.float32)
+    kv = _rand(rng, (1, 200, 2, 32), jnp.float32)
+    with pytest.raises(ValueError, match="do not tile"):
+        ops.flash_attention(q, kv, kv, causal=True)
+
+
+def test_ksweep_row_block_sized_or_refused():
+    """The k-sweep kernel sizes its row block to the VMEM budget, rejects
+    a block that breaks the apron grid, and refuses a row too wide for
+    any block instead of taking the whole shard."""
+    from repro.kernels import stencil
+    assert stencil._row_block(16384, 16384, 8, 4, None) % 16 == 0
+    with pytest.raises(ValueError, match="multiple of"):
+        stencil._row_block(64, 130, 8, 4, 24)
+    with pytest.raises(ValueError, match="VMEM"):
+        stencil._row_block(64, 1 << 22, 8, 4, None)

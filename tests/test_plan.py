@@ -8,12 +8,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import cost_model, instrument, managed
 from repro.core.region import CommRegion
 from repro.core.tuner import ScheduleTuner, replan_program_plans
+from repro.parallel import smap
 from repro.plan import (CommOp, candidates_for, crosscheck_collectives,
                         lower_collectives, plan_program)
 from repro.plan.planner import ProgramPlan, contention_sets, joint_cost
@@ -94,8 +94,7 @@ def test_instrument_extracts_two_axes():
         s = lax.psum(b, "y")
         return g.sum() + s.sum()
 
-    f = shard_map(body, mesh=mesh, in_specs=(P("x"), P(None)),
-                  out_specs=P(), check_rep=False)
+    f = smap(body, mesh, in_specs=(P("x"), P(None)), out_specs=P())
     rep = instrument.analyze_region(f, jnp.ones((4, 2), jnp.float32),
                                     jnp.ones((3,), jnp.float32))
     got = {(c.primitive, c.axis): c.nbytes for c in rep.collectives}

@@ -107,3 +107,22 @@ def test_straggler_detection():
     params, opt, s0 = loop.init_state()
     out = loop.run(params, opt, s0)
     assert 8 in out["stragglers"]
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The persistent compilation cache stays where JAX_COMPILATION_CACHE_DIR
+    says; without it, it goes to the checkout's fixed .jax_cache."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.use_compile_cache()
+        assert got.endswith(".jax_cache")
+        assert os.path.dirname(got) == os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
