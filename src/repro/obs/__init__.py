@@ -3,10 +3,11 @@ managed subsystem, cross-cutting the other six).
 
 Four pieces, one loop:
 
-* ``tracer``   — zero-dependency span/event tracer (bounded ring,
-  thread-correct nesting, free when disabled);
-* ``registry`` — ONE metrics registry (counters/gauges/histograms/EWMA/
-  extrema) that serve/, checkpoint/ and the train loop build on;
+* ``tracer``   — zero-dependency span tracer (bounded ring,
+  thread-correct nesting, free when disabled, and on the profiler's
+  clock while a ``jax.profiler`` session records);
+* ``registry`` — ONE metrics registry (counters/histograms/EWMA/extrema)
+  that serve/, checkpoint/ and the train loop build on;
 * ``export``   — Chrome-trace-event/Perfetto JSON with per-mesh-axis
   comm tracks + DecisionRecord instants, and measured in-flight windows
   for mdmplint pass 4;
@@ -25,9 +26,9 @@ from repro.obs.calibrate import (CalibrationLedger, CalibrationSample,
 from repro.obs.export import (load_trace, measured_windows,
                               to_chrome_trace, trace_tracks,
                               write_chrome_trace)
-from repro.obs.registry import (Counter, Ewma, Extremum, Gauge,
-                                Histogram, MetricsRegistry)
-from repro.obs.tracer import (NULL, Instant, NullTracer, Span, Tracer,
+from repro.obs.registry import (Counter, Ewma, Extremum, Histogram,
+                                MetricsRegistry)
+from repro.obs.tracer import (NULL, NullTracer, Span, Tracer,
                               dispatch_span, get_tracer, install_tracer,
                               use_tracer)
 
@@ -36,8 +37,7 @@ __all__ = [
     "chosen_predicted_s", "cover_with",
     "load_trace", "measured_windows", "to_chrome_trace", "trace_tracks",
     "write_chrome_trace",
-    "Counter", "Ewma", "Extremum", "Gauge", "Histogram",
-    "MetricsRegistry",
-    "NULL", "Instant", "NullTracer", "Span", "Tracer", "dispatch_span",
+    "Counter", "Ewma", "Extremum", "Histogram", "MetricsRegistry",
+    "NULL", "NullTracer", "Span", "Tracer", "dispatch_span",
     "get_tracer", "install_tracer", "use_tracer",
 ]

@@ -24,7 +24,7 @@ from __future__ import annotations
 import json
 from typing import Any, Iterable, Sequence
 
-from repro.obs.tracer import Instant, Span, Tracer
+from repro.obs.tracer import Span, Tracer
 
 #: export track for spans that declare neither ``track`` nor ``axis``
 DEFAULT_TRACK = "compute"
@@ -57,17 +57,14 @@ def to_chrome_trace(tracer: Tracer, decisions: Sequence[Any] = (),
     """Assemble the Trace Event Format dict.  Timestamps are rebased to
     the earliest event so the trace starts at ts=0."""
     spans = tracer.spans()
-    instants = tracer.instants()
     stamped = [r for r in decisions if getattr(r, "t", None) is not None]
 
-    origins = ([s.t0 for s in spans] + [i.t for i in instants]
-               + [r.t for r in stamped])
+    origins = [s.t0 for s in spans] + [r.t for r in stamped]
     t_origin = min(origins, default=tracer.t_origin)
 
     # stable track -> tid mapping: decisions first, then sorted names
     tracks: dict[str, int] = {DECISION_TRACK: 0}
-    names = sorted({track_of(s.name, s.attrs) for s in spans}
-                   | {track_of(i.name, i.attrs) for i in instants})
+    names = sorted({track_of(s.name, s.attrs) for s in spans})
     for n in names:
         tracks.setdefault(n, len(tracks))
 
@@ -80,12 +77,6 @@ def to_chrome_trace(tracer: Tracer, decisions: Sequence[Any] = (),
             "ph": "X", "pid": 0, "tid": tracks[track_of(s.name, s.attrs)],
             "name": s.name, "ts": (s.t0 - t_origin) * 1e6,
             "dur": s.dur * 1e6, "args": dict(s.attrs, depth=s.depth)})
-    for i in instants:
-        events.append({
-            "ph": "i", "s": "t", "pid": 0,
-            "tid": tracks[track_of(i.name, i.attrs)],
-            "name": i.name, "ts": (i.t - t_origin) * 1e6,
-            "args": dict(i.attrs)})
     for rec in decisions:
         t = getattr(rec, "t", None)
         ts = (t - t_origin) * 1e6 if t is not None else 0.0

@@ -1,4 +1,4 @@
-"""One metrics registry — counters/gauges/histograms/EWMAs/extrema.
+"""One metrics registry — counters/histograms/EWMAs/extrema.
 
 Before this module, three subsystems hand-rolled the same estimators:
 ``serve/metrics.py`` kept a min-over-quanta step estimator, ``checkpoint/
@@ -36,16 +36,6 @@ class Counter:
 
     def add(self, n: int | float = 1) -> None:
         self.value += n
-
-
-class Gauge:
-    __slots__ = ("value",)
-
-    def __init__(self) -> None:
-        self.value: float | None = None
-
-    def set(self, v: float) -> None:
-        self.value = float(v)
 
 
 class Extremum:
@@ -151,7 +141,7 @@ class Histogram:
 class MetricsRegistry:
     """Get-or-create registry keyed by metric name.  Re-requesting a name
     returns the same object (and asserts the kind matches — a name that
-    is a counter in one module and a gauge in another is a bug)."""
+    is a counter in one module and a histogram in another is a bug)."""
 
     metrics: dict[str, Any] = dataclasses.field(default_factory=dict)
 
@@ -168,9 +158,6 @@ class MetricsRegistry:
     def counter(self, name: str) -> Counter:
         return self._get(name, Counter, Counter)
 
-    def gauge(self, name: str) -> Gauge:
-        return self._get(name, Gauge, Gauge)
-
     def histogram(self, name: str, window: int = 4096) -> Histogram:
         return self._get(name, lambda: Histogram(window), Histogram)
 
@@ -186,8 +173,6 @@ class MetricsRegistry:
         out: dict[str, Any] = {}
         for name, m in sorted(self.metrics.items()):
             if isinstance(m, Counter):
-                out[name] = m.value
-            elif isinstance(m, Gauge):
                 out[name] = m.value
             elif isinstance(m, Extremum):
                 out[name] = {"kind": m.kind, "value": m.value,

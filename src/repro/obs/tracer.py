@@ -1,24 +1,30 @@
-"""mdmptrace — the zero-dependency span/event tracer (the SEVENTH managed
+"""mdmptrace — the zero-dependency span tracer (the SEVENTH managed
 subsystem's sensor).
 
 MDMP's contract is "implement communications optimally using information
 provided by the user and data collected from instrumenting the code" —
-this module is the *collecting* half at runtime granularity: every hot
-path (serve quanta/swaps, pipeline ticks, halo exchange dispatches,
-attention ring steps, MoE dispatch chunks, checkpoint snapshot/drain/
-commit, planner resolution, lint preflight) opens a :class:`Span` under
-the ambient tracer, and the exporters (``obs/export.py``) and the
-calibration ledger (``obs/calibrate.py``) consume the resulting event
-stream.
+this module is the *collecting* half at runtime granularity: the serving
+runtime's per-quantum phases, train steps, checkpoint snapshot/drain/
+commit, swap-outs and swap-ins, planner resolution and lint preflight
+open a :class:`Span` under the ambient tracer, and the exporters
+(``obs/export.py``) and the calibration ledger (``obs/calibrate.py``)
+consume the resulting event stream.
+
+One span API, two sinks.  While a ``jax.profiler`` session records
+(``jax.profiler.trace`` around the run, or the benchmark's ``--trace 1``),
+every ``span()`` — the shared disabled one included — also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so the program's
+phases sit on the device trace's clock, nested as the code nests.  The
+annotation carries the name and the scalar attrs of
+:data:`ANNOTATED_ATTRS` only.  There is no other switch: with no
+profiler session a span pays one ``TraceAnnotation.is_enabled()`` check.
 
 Design constraints, in order:
 
 1.  **Disabled is free.**  The default ambient tracer is a shared
     :data:`NULL` singleton whose ``span()`` returns one reusable no-op
-    context manager — no allocation beyond the kwargs dict at the call
-    site, no clock reads, no list growth.  ``bench_trace_overhead``
-    asserts the enabled path costs <2% of a step and the disabled path
-    is bit-identical to untraced code.
+    context manager while no profiler records — no allocation beyond the
+    kwargs dict at the call site, no clock reads, no list growth.
 2.  **Bounded.**  Events land in a ``deque(maxlen=capacity)`` ring so a
     week-long serve run cannot OOM the host; the drop count is kept.
 3.  **Thread-correct.**  Span nesting is tracked per thread (the
@@ -44,7 +50,7 @@ import dataclasses
 import threading
 import time
 from collections import deque
-from typing import Any, Iterator
+from typing import Any
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,14 +72,29 @@ class Span:
         return self.t0 + self.dur
 
 
-@dataclasses.dataclass(frozen=True)
-class Instant:
-    """A zero-duration event (DecisionRecords export as these)."""
+#: the span attrs a profiler annotation carries beside the name (scalars
+#: a trace viewer shows); every other attr stays on the ring's Span only
+ANNOTATED_ATTRS = ("chunk", "quantum", "step")
 
-    name: str
-    t: float
-    tid: int
-    attrs: dict[str, Any]
+#: lazily-resolved ``jax.profiler.TraceAnnotation`` (False when jax is
+#: unavailable) — one import, not one per span
+_ANNOTATION_CLS: Any = None
+
+
+def _annotation(name: str, attrs: dict[str, Any]) -> Any:
+    """A profiler annotation for a span while a ``jax.profiler`` session
+    records, else None (one ``is_enabled()`` check)."""
+    global _ANNOTATION_CLS
+    cls = _ANNOTATION_CLS
+    if cls is None:
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:     # no jax, no annotations
+            cls = False
+        _ANNOTATION_CLS = cls
+    if not cls or not cls.is_enabled():
+        return None
+    return cls(name, **{k: attrs[k] for k in ANNOTATED_ATTRS if k in attrs})
 
 
 class _NullSpan:
@@ -91,23 +112,37 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _AnnotatedNullSpan:
+    """The disabled tracer's span while a profiler records: the profiler
+    annotation alone, nothing on the ring."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann: Any):
+        self._ann = ann
+
+    def __enter__(self) -> None:
+        self._ann.__enter__()
+        return None
+
+    def __exit__(self, *exc: Any) -> bool:
+        self._ann.__exit__(*exc)
+        return False
+
+
 class NullTracer:
-    """The disabled tracer: every operation is a no-op, every query is
-    empty.  ONE shared instance (:data:`NULL`) serves the whole process."""
+    """The disabled tracer: every query is empty; a span is a no-op, or
+    a profiler annotation while a profiler session records.  ONE shared
+    instance (:data:`NULL`) serves the whole process."""
 
     __slots__ = ()
     enabled = False
 
-    def span(self, name: str, **attrs: Any) -> _NullSpan:
-        return _NULL_SPAN
-
-    def instant(self, name: str, **attrs: Any) -> None:
-        return None
+    def span(self, name: str, **attrs: Any) -> _NullSpan | _AnnotatedNullSpan:
+        ann = _annotation(name, attrs)
+        return _NULL_SPAN if ann is None else _AnnotatedNullSpan(ann)
 
     def spans(self) -> list[Span]:
-        return []
-
-    def instants(self) -> list[Instant]:
         return []
 
 
@@ -116,10 +151,11 @@ NULL = NullTracer()
 
 class _SpanCtx:
     """The live context manager: clocks on enter/exit, ring append on
-    exit.  A plain class (not ``contextlib.contextmanager``) keeps the
-    per-span overhead to two attribute writes and two clock reads."""
+    exit, and the profiler annotation while a profiler records.  A plain
+    class (not ``contextlib.contextmanager``) keeps the per-span overhead
+    to a few attribute writes and two clock reads."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_depth")
+    __slots__ = ("_tracer", "_name", "_attrs", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict[str, Any]):
         self._tracer = tracer
@@ -130,11 +166,16 @@ class _SpanCtx:
         stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self)
+        self._ann = _annotation(self._name, self._attrs)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         tr = self._tracer
         tr._stack().pop()
         tr._events.append(Span(
@@ -151,15 +192,14 @@ class _SpanCtx:
 
 
 class Tracer:
-    """The live tracer: a bounded ring of :class:`Span`/:class:`Instant`
-    events with per-thread nesting stacks."""
+    """The live tracer: a bounded ring of :class:`Span` events with
+    per-thread nesting stacks."""
 
     enabled = True
 
     def __init__(self, capacity: int = 65536):
         self.capacity = int(capacity)
         self._events: deque[Span] = deque(maxlen=self.capacity)
-        self._instants: deque[Instant] = deque(maxlen=self.capacity)
         self._local = threading.local()
         self.t_origin = time.perf_counter()
         self.n_spans = 0              # total ever opened (ring may drop)
@@ -178,18 +218,10 @@ class Tracer:
         axis="serve", nbytes=..., scale=tokens): ...``"""
         return _SpanCtx(self, name, attrs)
 
-    def instant(self, name: str, **attrs: Any) -> None:
-        self._instants.append(Instant(
-            name=name, t=time.perf_counter(),
-            tid=threading.get_ident(), attrs=attrs))
-
     # -- queries -------------------------------------------------------------
 
     def spans(self) -> list[Span]:
         return list(self._events)
-
-    def instants(self) -> list[Instant]:
-        return list(self._instants)
 
     @property
     def dropped(self) -> int:
@@ -198,7 +230,6 @@ class Tracer:
 
     def clear(self) -> None:
         self._events.clear()
-        self._instants.clear()
         self.n_spans = 0
 
 
@@ -240,12 +271,6 @@ class use_tracer:
 
     def __exit__(self, *exc: Any) -> None:
         _STATE.tracer = self._old
-
-
-def iter_spans(tracer: Tracer, name_prefix: str = "") -> Iterator[Span]:
-    for s in tracer.spans():
-        if s.name.startswith(name_prefix):
-            yield s
 
 
 def dispatch_span(name: str, operand: Any = None, **attrs: Any):

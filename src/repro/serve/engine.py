@@ -195,9 +195,12 @@ class ServeEngine:
                 jnp.asarray(np.ones(self.slots, np.int32)), zeros, zeros)
 
     def warmup(self, chunk: int) -> None:
-        """Compile the quantum function outside the measured loop."""
-        out, self.cache = self._step_fn(chunk)(*self._idle_args(chunk))
-        jax.block_until_ready(out)
+        """Compile the quantum function outside the measured loop: one
+        idle quantum of ``chunk`` steps, noted in ``metrics.warmups``."""
+        self.metrics.note_warmup(chunk)
+        with get_tracer().span("serve.warmup", track="serve", chunk=chunk):
+            out, self.cache = self._step_fn(chunk)(*self._idle_args(chunk))
+            jax.block_until_ready(out)
 
     def lower_quantum(self, chunk: int) -> Any:
         """The quantum of ``chunk`` steps, lowered (``jax.stages.Lowered``)
@@ -503,8 +506,12 @@ class ServeEngine:
         if not sch.has_work() and not (
                 self.fault_plan and self.fault_plan.unfired()):
             return {}
-        sch.decide(self._n_params, self._dtype_bytes,
-                   dtype_str=self.model.cfg.dtype)
+        tr = get_tracer()
+        with tr.span("serve.decide", track="serve") as sp:
+            sch.decide(self._n_params, self._dtype_bytes,
+                       dtype_str=self.model.cfg.dtype)
+            if sp is not None:
+                sp.note(chunk=sch.chunk, source=sch.decided_from)
         if sch.chunk is None:       # queue was empty (pure fault drive)
             return self.results
         self.warmup(sch.chunk)
@@ -515,25 +522,30 @@ class ServeEngine:
         results = self.results
         while sch.has_work():
             self._apply_overload_events()
-            for rs in sch.admit(self.pt, hold=self._hold):
-                if rs.req.rid in self._swapped:
-                    self._swap_in(rs)
-            plan = sch.plan_quantum(sch.chunk)
-            if int(plan.steps.sum()) == 0:
-                # admit() ran just above with an empty batch and still
-                # produced nothing: the head request can never fit
-                raise RuntimeError(
-                    "serve queue stalled: request exceeds the page pool "
-                    f"({self.cache_cfg})")
-            stalled = []
-            for slot in sorted(sch.active):
-                rs = sch.active[slot]
-                try:
-                    self.pt.ensure(slot,
-                                   rs.consumed + int(plan.steps[slot]))
-                except PagePoolExhausted:
-                    stalled.append(slot)
-            if stalled and self._handle_exhaustion(plan, stalled):
+            with tr.span("serve.admit", track="serve"):
+                for rs in sch.admit(self.pt, hold=self._hold):
+                    if rs.req.rid in self._swapped:
+                        self._swap_in(rs)
+            with tr.span("serve.plan", track="serve"):
+                plan = sch.plan_quantum(sch.chunk)
+                if int(plan.steps.sum()) == 0:
+                    # admit() ran just above with an empty batch and
+                    # still produced nothing: the head request can never
+                    # fit
+                    raise RuntimeError(
+                        "serve queue stalled: request exceeds the page "
+                        f"pool ({self.cache_cfg})")
+                stalled = []
+                for slot in sorted(sch.active):
+                    rs = sch.active[slot]
+                    try:
+                        self.pt.ensure(slot,
+                                       rs.consumed + int(plan.steps[slot]))
+                    except PagePoolExhausted:
+                        stalled.append(slot)
+                evicted = bool(stalled) and self._handle_exhaustion(
+                    plan, stalled)
+            if evicted:
                 continue              # victim evicted: re-admit, re-plan
             if int(plan.steps.sum()) == 0:
                 continue              # whole batch stalled this quantum
@@ -547,26 +559,36 @@ class ServeEngine:
             t0 = time.perf_counter()
             # scale = useful slot-steps: dur/scale is measured seconds
             # per token, the unit resolve_serve_schedule predicts
-            with get_tracer().span(
+            with tr.span(
                     "serve.quantum", op="serve_schedule", axis="serve",
                     track="serve", chunk=plan.chunk, scale=useful,
                     quantum=self._quantum_idx - 1, reads="kv_pages"):
-                out, self.cache = self._step_fn(plan.chunk)(
-                    self.params, self.cache, jnp.asarray(self.pt.table),
-                    jnp.asarray(plan.tokens), jnp.asarray(plan.n_in),
-                    jnp.asarray(plan.pos), jnp.asarray(plan.steps))
-                out_np = np.asarray(out)
+                with tr.span("serve.upload", track="serve"):
+                    args = (jnp.asarray(self.pt.table),
+                            jnp.asarray(plan.tokens),
+                            jnp.asarray(plan.n_in), jnp.asarray(plan.pos),
+                            jnp.asarray(plan.steps))
+                with tr.span("serve.dispatch", track="serve"):
+                    out, self.cache = self._step_fn(plan.chunk)(
+                        self.params, self.cache, *args)
+                with tr.span("serve.readback", track="serve"):
+                    out_np = np.asarray(out)
             wall = time.perf_counter() - t0
             self._hold.clear()    # a quantum dispatched: evictees may
             # re-enter admission on the next planning round
-            self.metrics.note_quantum(wall, plan.chunk, useful,
-                                      self.slots)
-            self.recal.note(wall / max(1, plan.chunk))
-            for rs in sch.complete_quantum(plan, out_np, self.pt,
-                                           self.metrics):
-                self._retire(rs.req.rid, rs.generated)
+            with tr.span("serve.complete", track="serve"):
+                self.metrics.note_quantum(wall, plan.chunk, useful,
+                                          self.slots)
+                self.recal.note(wall / max(1, plan.chunk))
+                for rs in sch.complete_quantum(plan, out_np, self.pt,
+                                               self.metrics):
+                    self._retire(rs.req.rid, rs.generated)
             prev = (sch.mode, sch.chunk)
-            self._maybe_retune()
+            with tr.span("serve.retune", track="serve") as sp:
+                self._maybe_retune()
+                if sp is not None:
+                    sp.note(chunk_before=prev[1], chunk=sch.chunk,
+                            step_s=self.metrics.step_s_estimate())
             if sch.has_work() and (sch.mode, sch.chunk) != prev:
                 # the correction changed the schedule: compile the new
                 # quantum OUTSIDE the measured loop, keep the compile out

@@ -61,6 +61,10 @@ class ServeMetrics:
         self.traces: dict[int, RequestTrace] = {}
         self.sheds: list[tuple[int, str]] = []      # (rid, reason)
         self.preempts: list[tuple[int, str]] = []   # (rid, policy)
+        #: (perf_counter seconds, C) of each idle warm-up quantum the
+        #: engine ran: at each entry into ``run()`` with work, and after
+        #: each retune that changed C
+        self.warmups: list[tuple[float, int]] = []
         self._swap_bytes = self.reg.counter("serve.swap_bytes")
         self._swap_s = self.reg.counter("serve.swap_s")
         # "the min is the noise-robust estimator on a shared host"
@@ -119,6 +123,9 @@ class ServeMetrics:
                                          slots))
         self._step_min.observe(wall_s / max(1, chunk))
         self._quantum_wall.observe(wall_s)
+
+    def note_warmup(self, chunk: int) -> None:
+        self.warmups.append((time.perf_counter(), int(chunk)))
 
     def rebase_pending(self) -> None:
         """Move not-yet-served requests' submit times to 'now' — called

@@ -141,6 +141,10 @@ class ServeScheduler:
         self.mode: str | None = None
         self.chunk: int | None = None
         self.decision = None
+        #: "measured" where measured step seconds or the tuner's measured
+        #: comparison fed the last decision, "prior" where the cost
+        #: model's prior (or a caller's pin) alone decided
+        self.decided_from: str | None = None
         self.tuner_key: str | None = None
 
     # -- the managed decision ------------------------------------------------
@@ -159,6 +163,7 @@ class ServeScheduler:
         news = [r.max_new for r in reqs]
         pin_mode = None if self.schedule == "auto" else self.schedule
         pin_chunk = self._pinned_chunk
+        measured = measured_step_s is not None
         if self.tuner is not None:
             entry = self.tuner.decide_serve(
                 self.slots, int(np.mean(prompts)), int(np.mean(news)),
@@ -170,6 +175,7 @@ class ServeScheduler:
                 # the model seed; one measurement is just the status quo
                 # and must not lock out the online correction
                 pin_mode = entry.mode
+                measured = True
                 if pin_chunk is None:
                     pin_chunk = entry.chunks
         self.decision = managed.resolve_serve_schedule(
@@ -181,6 +187,7 @@ class ServeScheduler:
             schedule=pin_mode, chunk=pin_chunk)
         self.mode = self.decision.mode
         self.chunk = self.decision.chunk
+        self.decided_from = "measured" if measured else "prior"
 
     # -- queue wait / SLO estimates ------------------------------------------
 

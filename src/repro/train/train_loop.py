@@ -490,17 +490,20 @@ class TrainLoop:
             for leaf in jax.tree.leaves({"params": params, "opt": opt})
             if hasattr(leaf, "size"))
         while step < cfg.total_steps:
-            batch = self._batch(step)
+            with tr.span("train.batch", track="compute", step=step):
+                batch = self._batch(step)
             t0 = time.monotonic()
             try:
                 if self.fault_hook is not None:
                     self.fault_hook(step)
                 with tr.span("train.step", track="compute", step=step):
-                    params, opt, metrics = self.step_fn(params, opt,
-                                                        batch)
+                    with tr.span("train.dispatch", track="compute"):
+                        params, opt, metrics = self.step_fn(params, opt,
+                                                            batch)
                     # float() blocks on the device — the span measures
                     # the realized step, not the dispatch
-                    loss = float(metrics["loss"])
+                    with tr.span("train.sync", track="compute"):
+                        loss = float(metrics["loss"])
                 if not np.isfinite(loss):
                     raise FloatingPointError(f"non-finite loss at {step}")
             except Exception as e:          # noqa: BLE001 — restart path

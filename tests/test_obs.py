@@ -18,8 +18,8 @@ from repro.obs.calibrate import (CalibrationLedger, Recalibrator,
                                  chosen_predicted_s, cover_with)
 from repro.obs.export import (measured_windows, to_chrome_trace,
                               trace_tracks)
-from repro.obs.registry import (Counter, Ewma, Extremum, Gauge,
-                                Histogram, MetricsRegistry)
+from repro.obs.registry import (Counter, Ewma, Extremum, Histogram,
+                                MetricsRegistry)
 from repro.obs.tracer import (NULL, Span, Tracer, dispatch_span,
                               get_tracer, use_tracer)
 
@@ -39,11 +39,12 @@ def _span(name, t0, dur, **attrs):
 
 
 def test_counter_gauge():
-    c, g = Counter(), Gauge()
+    c = Counter()
+    assert c.value == 0
     c.add(3)
     c.add(2.5)
-    g.set(7)
-    assert c.value == 5.5 and g.value == 7
+    c.add()
+    assert c.value == 6.5
 
 
 def test_extremum_min_max_and_empty():
@@ -83,7 +84,7 @@ def test_registry_get_or_create_and_kind_clash():
     c1 = reg.counter("a.b")
     assert reg.counter("a.b") is c1        # same name -> same metric
     with pytest.raises(AssertionError):
-        reg.gauge("a.b")                   # name reuse across kinds
+        reg.histogram("a.b")               # name reuse across kinds
     reg.extremum("m", kind="min").observe(2.0)
     snap = reg.snapshot()
     assert snap["m"]["value"] == 2.0 and "a.b" in snap
@@ -123,6 +124,44 @@ def test_disabled_is_free_shared_noop():
     with a:
         pass
     assert NULL.spans() == [] and dispatch_span("z") is a
+
+
+def _profiled_host_events(logdir):
+    """name -> [(start_ns, end_ns, stats)] of a trace's host events."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.end_ns, dict(ev.stats)))
+    return out
+
+
+def test_spans_annotate_the_profiler_only_while_it_records(tmp_path):
+    jax = pytest.importorskip("jax")
+    tr = Tracer()
+    with NULL.span("serve.quantum", chunk=2), tr.span("serve.dispatch"):
+        pass
+    # no profiler session: the disabled span is the shared no-op
+    assert NULL.span("serve.quantum") is NULL.span("x")
+    with jax.profiler.trace(str(tmp_path)):
+        with NULL.span("serve.quantum", chunk=2, quantum=7,
+                       op="serve_schedule") as sp:
+            assert sp is None
+            with tr.span("serve.dispatch", step=3):
+                pass
+    evs = _profiled_host_events(tmp_path)
+    (q0, q1, qstats), = evs["serve.quantum"]
+    (d0, d1, dstats), = evs["serve.dispatch"]
+    assert q0 <= d0 <= d1 <= q1
+    # the annotation carries the scalar attrs it names, and no others
+    assert qstats == {"chunk": 2, "quantum": 7} and dstats == {"step": 3}
+    # the live tracer's ring records with or without the profiler
+    assert [s.name for s in tr.spans()] == ["serve.dispatch"] * 2
 
 
 def test_use_tracer_scoped_and_note():
@@ -182,7 +221,6 @@ def test_chrome_trace_schema():
             with tr.span("halo.solve", op="halo_aggregation", axis="x",
                          nbytes=64, scale=10):
                 pass
-        tr.instant("fault", kind="transient")
     rec = _rec()
     managed.log_decision(rec)
     doc = to_chrome_trace(tr, [rec])
@@ -209,7 +247,9 @@ def test_chrome_trace_schema():
     assert step["ts"] <= halo["ts"]
     assert halo["ts"] + halo["dur"] <= step["ts"] + step["dur"] + 1e-6
 
+    # the only instants are the decisions, on their own track
     dec = [e for e in instants if e["tid"] == 0]
+    assert dec == instants
     assert len(dec) == 1 and dec[0]["name"] == "decision:halo_aggregation"
     assert dec[0]["args"]["predicted_bulk_s"] == rec.predicted_bulk_s
     assert dec[0]["args"]["predicted_interleaved_s"] \

@@ -192,6 +192,94 @@ def test_paged_engine_uses_pallas_kernel(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# The serving runtime's spans and warm-up counter
+# ---------------------------------------------------------------------------
+
+
+def _span_engine(first_chunk=1, retuned_chunk=2):
+    """A two-slot engine whose first decision takes ``first_chunk`` and
+    every retune ``retuned_chunk`` (the measured correction pinned, so
+    the test does not rest on host timing), with four queued requests."""
+    cfg, mesh, model, params = _build("granite-34b")
+    eng = ServeEngine(model, mesh, params, slots=2, max_seq=32,
+                      page_size=4)
+    sch = eng.scheduler
+    decide = sch.decide
+
+    def pinned(*a, **kw):
+        first = sch.chunk is None
+        decide(*a, **kw)
+        sch.mode = "continuous"
+        sch.chunk = first_chunk if first else retuned_chunk
+
+    sch.decide = pinned
+    rng = np.random.default_rng(3)
+    for plen in (4, 3, 5, 2):
+        eng.submit(rng.integers(0, cfg.vocab_size - 1, size=plen), 6)
+    return eng
+
+
+def test_engine_spans_nest_on_the_profiler_clock(tmp_path):
+    """With no tracer installed, a profiler session alone puts the
+    engine's phases in the trace, each quantum's dispatch inside it."""
+    import glob
+    from jax.profiler import ProfileData
+    eng = _span_engine(first_chunk=2, retuned_chunk=2)
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run()
+    path, = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    evs: dict[str, list] = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    evs.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.end_ns))
+    quanta, dispatches = evs["serve.quantum"], evs["serve.dispatch"]
+    assert len(quanta) == len(dispatches) == len(eng.metrics.quanta)
+    for d0, d1 in dispatches:
+        assert any(q0 <= d0 and d1 <= q1 for q0, q1 in quanta)
+    for name in ("serve.decide", "serve.warmup", "serve.admit",
+                 "serve.plan", "serve.upload", "serve.readback",
+                 "serve.complete", "serve.retune"):
+        assert name in evs, name
+
+
+def test_engine_spans_in_order_and_warmups_counted():
+    """The live tracer sees every quantum's phases in order, and the
+    metrics note one warm-up at entry and one after the retune that
+    changed C."""
+    from repro.obs import Tracer, use_tracer
+    eng = _span_engine(first_chunk=1, retuned_chunk=2)
+    tr = Tracer()
+    with use_tracer(tr):
+        eng.run()
+    assert [c for _, c in eng.metrics.warmups] == [1, 2]
+    spans = sorted(tr.spans(), key=lambda s: s.t0)
+    top = [s.name for s in spans if s.depth == 0]
+    n = len(eng.metrics.quanta)
+    assert top[:2] == ["serve.decide", "serve.warmup"]
+    phases = [t for t in top[2:] if t != "serve.warmup"]
+    assert phases == ["serve.admit", "serve.plan", "serve.quantum",
+                      "serve.complete", "serve.retune"] * n
+    # one retune moved C from 1 to 2, and the second warm-up follows it
+    moved = [(s.attrs["chunk_before"], s.attrs["chunk"]) for s in spans
+             if s.name == "serve.retune"
+             and s.attrs["chunk_before"] != s.attrs["chunk"]]
+    assert moved == [(1, 2)]
+    assert top[top.index("serve.warmup", 2) - 1] == "serve.retune"
+    decide = spans[0]
+    assert decide.attrs == {"track": "serve", "chunk": 1,
+                            "source": "prior"}
+    children = [s.name for s in spans if s.depth == 1
+                and s.name.startswith(("serve.upload", "serve.dispatch",
+                                       "serve.readback"))]
+    assert children == ["serve.upload", "serve.dispatch",
+                        "serve.readback"] * n
+    assert len(eng.results) == 4
+
+
+# ---------------------------------------------------------------------------
 # The managed decision + bookkeeping units
 # ---------------------------------------------------------------------------
 

@@ -126,3 +126,24 @@ def test_compile_cache_dir(monkeypatch):
         assert jax.config.jax_compilation_cache_dir == got
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_train_loop_spans(tmp_path):
+    """Each step opens train.batch, then train.step holding the dispatch
+    and the sync on the loss, in that order."""
+    from repro.obs import Tracer, use_tracer
+    model, opt_cfg, step_fn, pshard, bshard, data = _setup()
+    loop = TrainLoop(step_fn, model, opt_cfg, data,
+                     TrainLoopConfig(total_steps=2, ckpt_every=0,
+                                     ckpt_dir=str(tmp_path / "ckpt")),
+                     pshard, bshard)
+    params, opt, s0 = loop.init_state()
+    tr = Tracer()
+    with use_tracer(tr):
+        loop.run(params, opt, s0)
+    spans = sorted(tr.spans(), key=lambda s: s.t0)
+    assert [(s.name, s.depth) for s in spans] == [
+        ("train.batch", 0), ("train.step", 0), ("train.dispatch", 1),
+        ("train.sync", 1)] * 2
+    assert [s.attrs["step"] for s in spans if s.name == "train.batch"] \
+        == [0, 1]
