@@ -9,12 +9,18 @@ classic vLLM paged-attention shape.
 
 Two engines with identical math:
 
-  * ``paged_attention_pallas`` — the table rides in scalar-prefetch SMEM
-    (``pltpu.PrefetchScalarGridSpec``): the k/v BlockSpec index maps read
-    ``table[b, i]`` to pick which pool page the next grid step DMAs, so
-    the gather costs nothing beyond the page loads themselves.  The
-    (m, l, acc) online-softmax state accumulates across the page grid in
-    VMEM scratch exactly like kernels/flash_attention.py.
+  * ``paged_attention_pallas`` — a grid over slots whose work follows
+    the live pages, not the table's width.  The table and ``lens`` ride
+    in scalar-prefetch SMEM (``pltpu.PrefetchScalarGridSpec``); the K/V
+    pools stay in HBM (``pl.ANY``).  Each slot walks its live pages in
+    blocks of the fewest pages that cover 128 positions:
+    a ``fori_loop`` over the blocks copies each live page with
+    ``pltpu.make_async_copy`` into a double-buffered VMEM buffer, block
+    t+1's copies started before block t's compute.  Pages past
+    ``cdiv(lens, page)`` (and, under a window, wholly before it) are
+    neither copied nor looked up in the table; a slot with ``lens == 0``
+    copies nothing.  The (m, l, acc) online-softmax state accumulates in
+    f32 VMEM scratch exactly like kernels/flash_attention.py.
   * ``paged_attention_partials_jnp`` — a lax.scan over table columns that
     computes one flash partial per page and folds it with the
     ``merge_partials`` LSE combinator (the same combinator the ring
@@ -47,92 +53,137 @@ from repro.kernels.flash_attention import (NEG_INF, finalize_partials,
 Array = jax.Array
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, page: int, n_pages_max: int,
-                  window: int, scale: float, groups: int):
-    """Online-softmax accumulation over one slot's page chain.  Grid is
-    (B, n_pages_max) with pages innermost; the k/v refs already hold pool
-    page ``table[b, i]`` (the index maps did the gather)."""
-    i = pl.program_id(1)
+def live_page_range(lens, page: int, window: int = 0):
+    """[first, end) of the table pages holding the positions a query of
+    ``lens`` valid positions attends (``window`` > 0 drops the pages
+    wholly before ``lens - window``); ``lens == 0`` gives an empty range.
+    ``lens`` is a numpy or jax integer array (the kernel's is a traced
+    scalar, the serving engine's a host array)."""
+    end = (lens + page - 1) // page
+    if window <= 0:
+        return 0, end
+    return (lens - window).clip(0) // page, end
+
+
+def _paged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, m_scr, l_scr, acc_scr, *, page: int,
+                  ppb: int, window: int, scale: float, groups: int):
+    """Online-softmax accumulation over one slot's live pages.  Grid is
+    (B,); a loop walks the slot's live blocks of ``ppb`` pages, copying
+    block t+1's pages from the HBM pools into one half of the VMEM
+    buffers before block t's compute reads the other half."""
     b = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[...].astype(jnp.float32)                # [H, hd]
-    # Flatten the page to 2-D rows (pos, kv head) -> pos * KV + kv so both
-    # products are plain 2-D MXU dots; a q head scores only the columns
-    # of its own kv head (GQA: head h reads kv head h // groups).
-    kvh = k_ref.shape[1]
-    k = k_ref[...].astype(jnp.float32).reshape(page * kvh, -1)
-    v = v_ref[...].astype(jnp.float32).reshape(page * kvh, -1)
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale   # [H, page * KV]
-
     valid_len = len_ref[b]
-    col = lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    head = lax.broadcasted_iota(jnp.int32, logits.shape, 0)
-    kpos = i * page + col // kvh
-    mask = (kpos < valid_len) & (col % kvh == head // groups)
-    if window > 0:
-        mask &= kpos >= valid_len - window
-    logits = jnp.where(mask, logits, NEG_INF)
+    first, end = live_page_range(valid_len, page, window)
+    n_blocks = (end - first + ppb - 1) // ppb
 
-    m_prev = m_scr[...]                               # [H, 1]
-    l_prev = l_scr[...]
-    m_cur = jnp.max(logits, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new)
-    p = jnp.where(mask, p, 0.0)
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # [H, hd]
-    m_scr[...] = m_new
-    l_scr[...] = l_new
+    def block_copies(t, slot, start: bool):
+        """Start (or wait for) the copies of block t's live pages; pages
+        past ``end`` are neither copied nor looked up in the table."""
+        p0 = first + t * ppb
 
-    @pl.when(i == n_pages_max - 1)
-    def _finish():
-        o_ref[...] = (acc_scr[...]
-                      / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        def one(j, carry):
+            pid = tbl_ref[b, p0 + j]
+            for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                cp = pltpu.make_async_copy(
+                    hbm.at[pid], buf.at[slot, pl.ds(j * page, page)],
+                    sems.at[slot])
+                if start:
+                    cp.start()
+                else:
+                    cp.wait()
+            return carry
+
+        lax.fori_loop(0, jnp.minimum(ppb, end - p0), one, 0)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    q = q_ref[...].astype(jnp.float32)                # [H, hd]
+    kvh = k_buf.shape[2]
+
+    def block(t, carry):
+        slot = t % 2
+
+        @pl.when(t + 1 < n_blocks)
+        def _prefetch():
+            block_copies(t + 1, 1 - slot, start=True)
+
+        block_copies(t, slot, start=False)
+        # Flatten the block to 2-D rows (pos, kv head) -> pos * KV + kv so
+        # both products are plain 2-D MXU dots; a q head scores only the
+        # columns of its own kv head (GQA: head h reads kv head h // groups).
+        k = k_buf[slot].astype(jnp.float32).reshape(ppb * page * kvh, -1)
+        v = v_buf[slot].astype(jnp.float32).reshape(ppb * page * kvh, -1)
+        pos0 = (first + t * ppb) * page
+        # rows of pages that were not copied hold stale VMEM: zero them
+        # so a masked (zero) probability cannot meet a NaN
+        vpos = pos0 + lax.broadcasted_iota(jnp.int32, v.shape, 0) // kvh
+        v = jnp.where(vpos < valid_len, v, 0.0)
+        logits = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, ppb*page*KV]
+
+        col = lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        head = lax.broadcasted_iota(jnp.int32, logits.shape, 0)
+        kpos = pos0 + col // kvh
+        mask = (kpos < valid_len) & (col % kvh == head // groups)
+        if window > 0:
+            mask &= kpos >= valid_len - window
+        logits = jnp.where(mask, logits, NEG_INF)
+
+        m_prev = m_scr[...]                           # [H, 1]
+        l_prev = l_scr[...]
+        m_cur = jnp.max(logits, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(logits - m_new)
+        p = jnp.where(mask, p, 0.0)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)       # [H, hd]
+        m_scr[...] = m_new
+        l_scr[...] = l_new
+        return carry
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        block_copies(0, 0, start=True)
+
+    lax.fori_loop(0, n_blocks, block, 0)
+    o_ref[...] = (acc_scr[...]
+                  / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(q: Array, k_pages: Array, v_pages: Array,
                            table: Array, lens: Array, *, window: int = 0,
                            interpret: bool = False) -> Array:
     """q: [B, H, hd]; k_pages, v_pages: [n_pages, page, KV, hd];
-    table: [B, n_pages_max] int32 pool page ids (unused entries may hold
-    any in-range id — their positions are masked by ``lens``);
+    table: [B, n_pages_max] int32 pool page ids (entries past a slot's
+    live pages are never read, so they may hold anything);
     lens: [B] int32 valid lengths.  Returns [B, H, hd] in q's dtype."""
     b, h, hd = q.shape
-    n_pool, page, kvh, _ = k_pages.shape
-    n_pages_max = table.shape[1]
-    groups = h // kvh
-    scale = 1.0 / math.sqrt(hd)
-
+    _, page, kvh, _ = k_pages.shape
+    # pages per block: the fewest covering 128 positions (the MXU's
+    # width), at most the table's width
+    ppb = min(-(-128 // page), table.shape[1])
     kernel = functools.partial(
-        _paged_kernel, page=page, n_pages_max=n_pages_max, window=window,
-        scale=scale, groups=groups)
+        _paged_kernel, page=page, ppb=ppb, window=window,
+        scale=1.0 / math.sqrt(hd), groups=h // kvh)
 
-    kv_spec = pl.BlockSpec(
-        (None, page, kvh, hd),
-        lambda b_, i, tbl, ln: (tbl[b_, i], 0, 0, 0))
+    slot_spec = pl.BlockSpec((None, h, hd), lambda b_, tbl, ln: (b_, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # table, lens
-        grid=(b, n_pages_max),
-        in_specs=[
-            pl.BlockSpec((None, h, hd), lambda b_, i, tbl, ln: (b_, 0, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((None, h, hd),
-                               lambda b_, i, tbl, ln: (b_, 0, 0)),
+        grid=(b,),
+        in_specs=[slot_spec,
+                  pl.BlockSpec(memory_space=pl.ANY),    # pools stay in HBM
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=slot_spec,
         scratch_shapes=[
+            pltpu.VMEM((2, ppb * page, kvh, hd), k_pages.dtype),
+            pltpu.VMEM((2, ppb * page, kvh, hd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, hd), jnp.float32),

@@ -74,16 +74,16 @@ class Span:
 
 #: the span attrs a profiler annotation carries beside the name (scalars
 #: a trace viewer shows); every other attr stays on the ring's Span only
-ANNOTATED_ATTRS = ("chunk", "quantum", "step")
+ANNOTATED_ATTRS = ("chunk", "quantum", "step", "live_pages", "table_pages")
 
 #: lazily-resolved ``jax.profiler.TraceAnnotation`` (False when jax is
 #: unavailable) — one import, not one per span
 _ANNOTATION_CLS: Any = None
 
 
-def _annotation(name: str, attrs: dict[str, Any]) -> Any:
-    """A profiler annotation for a span while a ``jax.profiler`` session
-    records, else None (one ``is_enabled()`` check)."""
+def _profiler_annotation_cls() -> Any:
+    """``jax.profiler.TraceAnnotation`` while a profiler session records,
+    else None (one ``is_enabled()`` check)."""
     global _ANNOTATION_CLS
     cls = _ANNOTATION_CLS
     if cls is None:
@@ -92,7 +92,14 @@ def _annotation(name: str, attrs: dict[str, Any]) -> Any:
         except ImportError:     # no jax, no annotations
             cls = False
         _ANNOTATION_CLS = cls
-    if not cls or not cls.is_enabled():
+    return cls if cls and cls.is_enabled() else None
+
+
+def _annotation(name: str, attrs: dict[str, Any]) -> Any:
+    """A profiler annotation for a span while a ``jax.profiler`` session
+    records, else None."""
+    cls = _profiler_annotation_cls()
+    if cls is None:
         return None
     return cls(name, **{k: attrs[k] for k in ANNOTATED_ATTRS if k in attrs})
 
@@ -247,6 +254,13 @@ def get_tracer() -> NullTracer | Tracer:
     installed) — the ONE call every instrumentation site makes."""
     tr = getattr(_STATE, "tracer", None)
     return tr if tr is not None else _DEFAULT
+
+
+def recording() -> bool:
+    """Whether a span opened now lands anywhere: the ambient tracer is
+    live or a ``jax.profiler`` session records.  A site that computes
+    attrs only for the record asks first, so untraced runs skip it."""
+    return get_tracer().enabled or _profiler_annotation_cls() is not None
 
 
 def install_tracer(tracer: NullTracer | Tracer | None) -> None:

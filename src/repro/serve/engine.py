@@ -45,9 +45,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import cost_model, managed, overlap
 from repro.core.faults import FaultPlan
+from repro.kernels.paged_attention import live_page_range
 from repro.models.model import Model
 from repro.obs.calibrate import Recalibrator
-from repro.obs.tracer import get_tracer
+from repro.obs.tracer import get_tracer, recording
 from repro.parallel.sharding import smap, spec_pspecs
 from repro.serve.kv_cache import (PagedCacheConfig, PagePoolExhausted,
                                   PageTable)
@@ -568,7 +569,8 @@ class ServeEngine:
                             jnp.asarray(plan.tokens),
                             jnp.asarray(plan.n_in), jnp.asarray(plan.pos),
                             jnp.asarray(plan.steps))
-                with tr.span("serve.dispatch", track="serve"):
+                with tr.span("serve.dispatch", track="serve",
+                             **self._page_walk(plan)):
                     out, self.cache = self._step_fn(plan.chunk)(
                         self.params, self.cache, *args)
                 with tr.span("serve.readback", track="serve"):
@@ -598,6 +600,22 @@ class ServeEngine:
                 self.metrics.rebase_pending()
                 self._variant_q0 = len(self.metrics.quanta)
         return results
+
+    def _page_walk(self, plan) -> dict[str, int]:
+        """The ``serve.dispatch`` span's page counts for the quantum's
+        first step, from the host's plan, while a span is recorded:
+        ``live_pages``, summed over slots, the pages the paged kernel
+        walks per layer (with the model's sliding window), and
+        ``table_pages``, slots x table width, what a walk of every table
+        page would take."""
+        if not recording():
+            return {}
+        cc = self.cache_cfg
+        lens = np.where(plan.steps > 0, plan.pos + 1, 0)
+        first, end = live_page_range(lens, cc.page_size,
+                                     self.model.cfg.sliding_window)
+        return {"live_pages": int((end - first).sum()),
+                "table_pages": cc.slots * cc.max_pages_per_seq}
 
     def _maybe_retune(self) -> None:
         """The iteration-(k)->(k+1) correction: once enough quanta are

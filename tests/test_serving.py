@@ -34,15 +34,27 @@ from repro.train.serve_loop import Generator
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("h,kvh,hd,page,pmax", [
-    (8, 2, 32, 8, 5),     # GQA 4:1
-    (4, 4, 16, 4, 7),     # MHA, small pages
-    (8, 1, 64, 16, 3),    # MQA
+@pytest.mark.parametrize("h,kvh,hd,page,pmax,lens", [
+    pytest.param(8, 2, 32, 8, 5, None, id="8-2-32-8-5"),      # GQA 4:1
+    pytest.param(4, 4, 16, 4, 7, None, id="4-4-16-4-7"),      # MHA, small
+    pytest.param(8, 1, 64, 16, 3, None, id="8-1-64-16-3"),    # MQA
+    # the kernel walks blocks of 16 pages of 8 rows: 37 pages are three
+    # blocks, the last partly live; slots empty, ending on a block
+    # boundary, full (lens == page * pmax), and one row past a boundary
+    pytest.param(8, 2, 32, 8, 37, None, id="blocks-8-2-32-8-37"),
+    pytest.param(8, 2, 32, 8, 37, (0, 256, 296, 129),
+                 id="edges-8-2-32-8-37"),
+    # blocks of 32 pages of 4 rows; 70 is not a multiple of 32
+    pytest.param(4, 4, 16, 4, 70, (0, 128, 280, 131),
+                 id="edges-4-4-16-4-70"),
 ])
-@pytest.mark.parametrize("window", [0, 9])
-def test_paged_attention_pallas_vs_jnp(h, kvh, hd, page, pmax, window):
+# window 150 starts the walk inside a later block of the wide tables
+@pytest.mark.parametrize("window", [0, 9, 150])
+def test_paged_attention_pallas_vs_jnp(h, kvh, hd, page, pmax, lens,
+                                       window):
     rng = np.random.default_rng(h * 100 + page + window)
-    b, npool = 3, 32
+    b = 3 if lens is None else len(lens)
+    npool = max(32, b * pmax)
     q = jnp.asarray(rng.normal(size=(b, h, hd)).astype(np.float32))
     kp = jnp.asarray(rng.normal(size=(npool, page, kvh, hd))
                      .astype(np.float32))
@@ -50,8 +62,9 @@ def test_paged_attention_pallas_vs_jnp(h, kvh, hd, page, pmax, window):
                      .astype(np.float32))
     table = jnp.asarray(rng.permutation(npool)[:b * pmax]
                         .reshape(b, pmax).astype(np.int32))
-    lens = jnp.asarray(rng.integers(0, page * pmax + 1, size=b)
-                       .astype(np.int32))
+    if lens is None:
+        lens = rng.integers(0, page * pmax + 1, size=b)
+    lens = jnp.asarray(np.asarray(lens, np.int32))
     o_jnp = paged.paged_attention_jnp(q, kp, vp, table, lens,
                                       window=window)
     o_pal = paged.paged_attention_pallas(q, kp, vp, table, lens,
@@ -75,6 +88,23 @@ def test_paged_attention_pallas_vs_jnp(h, kvh, hd, page, pmax, window):
         np.testing.assert_allclose(np.asarray(want)[0, 0],
                                    np.asarray(o_jnp[i]), rtol=2e-5,
                                    atol=2e-5)
+
+
+def test_paged_attention_is_one_kernel_call():
+    """At serve-chat's shapes the kernel is one pallas_call whose result,
+    returned as is, has q's shape and dtype: the benchmark finds the
+    kernel in a device trace by that result type."""
+    sds = jax.ShapeDtypeStruct
+    q = sds((24, 32, 128), jnp.bfloat16)
+    pool = sds((3072, 8, 8, 128), jnp.bfloat16)
+    closed = jax.make_jaxpr(paged.paged_attention_pallas)(
+        q, pool, pool, sds((24, 128), jnp.int32), sds((24,), jnp.int32))
+    calls = [e for e in closed.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    out, = calls[0].outvars
+    assert closed.jaxpr.outvars == [out]
+    assert (out.aval.shape, out.aval.dtype) == (q.shape, q.dtype)
 
 
 def test_paged_partials_shard_merge():
@@ -229,16 +259,23 @@ def test_engine_spans_nest_on_the_profiler_clock(tmp_path):
         eng.run()
     path, = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
     evs: dict[str, list] = {}
+    stats: dict[str, list] = {}
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
                     evs.setdefault(ev.name, []).append(
                         (ev.start_ns, ev.end_ns))
+                    if ev.name == "serve.dispatch":
+                        stats.setdefault(ev.name, []).append(
+                            dict(ev.stats))
     quanta, dispatches = evs["serve.quantum"], evs["serve.dispatch"]
     assert len(quanta) == len(dispatches) == len(eng.metrics.quanta)
     for d0, d1 in dispatches:
         assert any(q0 <= d0 and d1 <= q1 for q0, q1 in quanta)
+    # the profiler alone records, so the dispatch carries its page counts
+    for st in stats["serve.dispatch"]:
+        assert 0 < st["live_pages"] <= st["table_pages"] == 2 * 8
     for name in ("serve.decide", "serve.warmup", "serve.admit",
                  "serve.plan", "serve.upload", "serve.readback",
                  "serve.complete", "serve.retune"):
@@ -276,6 +313,43 @@ def test_engine_spans_in_order_and_warmups_counted():
                                        "serve.readback"))]
     assert children == ["serve.upload", "serve.dispatch",
                         "serve.readback"] * n
+    assert len(eng.results) == 4
+
+
+def test_dispatch_span_counts_live_pages(monkeypatch):
+    """Each quantum's ``serve.dispatch`` span carries the pages its first
+    step walks per layer (the plan's live contexts) and the table's
+    width times the slots; an untraced run does not count them."""
+    from repro.obs import Tracer, use_tracer
+    from repro.serve import engine as engine_mod
+    eng = _span_engine(first_chunk=2, retuned_chunk=2)
+    plans = []
+    plan_quantum = eng.scheduler.plan_quantum
+
+    def keep(*a, **kw):
+        plans.append(plan_quantum(*a, **kw))
+        return plans[-1]
+
+    eng.scheduler.plan_quantum = keep
+    tr = Tracer()
+    with use_tracer(tr):
+        eng.run()
+    page = eng.cache_cfg.page_size
+    want = [sum(-(-(int(p) + 1) // page)
+                for p, n in zip(pl.pos, pl.steps) if n > 0)
+            for pl in plans if pl.steps.sum() > 0]
+    got = [s.attrs for s in tr.spans() if s.name == "serve.dispatch"]
+    assert [a["live_pages"] for a in got] == want
+    assert max(want) > 2            # some slot's context outgrew a page
+    assert {a["table_pages"] for a in got} == {2 * 8}
+    # untraced: the counts are never computed
+    eng = _span_engine(first_chunk=2, retuned_chunk=2)
+
+    def refuse(*a, **kw):
+        raise AssertionError("page counts computed in an untraced run")
+
+    monkeypatch.setattr(engine_mod, "live_page_range", refuse)
+    eng.run()
     assert len(eng.results) == 4
 
 

@@ -46,10 +46,11 @@ def one_chip(topo):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, *shapes) -> str:
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def test_flash_forward_compiles(one_chip):
@@ -66,13 +67,24 @@ def test_flash_carry_compiles(one_chip):
              ((1, 2048, 32), f32), ((1, 2048, 32, 128), f32))
 
 
-@pytest.mark.parametrize("page", [8, 16])
-def test_paged_attention_compiles(one_chip, page):
-    """4 slots of 288 positions (256-token prompts + 32 new tokens)."""
-    pages = 4 * 288 // page
-    _compile(paged_attention_pallas, one_chip, ((4, 32, 128), BF16),
-             ((pages, page, 8, 128), BF16), ((pages, page, 8, 128), BF16),
-             ((4, 288 // page), jnp.int32), ((4,), jnp.int32))
+@pytest.mark.parametrize("slots,page,pmax,pages", [
+    # 4 slots of 288 positions (256-token prompts + 32 new tokens)
+    pytest.param(4, 8, 36, 144, id="8"),
+    pytest.param(4, 16, 18, 72, id="16"),
+    # the serve-chat cell: 24 slots of 1024 positions, a 3072-page pool
+    pytest.param(24, 8, 128, 3072, id="serve-chat"),
+])
+def test_paged_attention_compiles(one_chip, slots, page, pmax, pages):
+    """The manual-DMA kernel and its VMEM buffers compile; the HLO holds
+    one kernel call whose result is q's shape and dtype."""
+    text = _compile(paged_attention_pallas, one_chip,
+                    ((slots, 32, 128), BF16), ((pages, page, 8, 128), BF16),
+                    ((pages, page, 8, 128), BF16),
+                    ((slots, pmax), jnp.int32), ((slots,), jnp.int32))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    assert f"bf16[{slots},32,128]" in calls[0].split("custom-call(")[0]
 
 
 def test_grouped_expert_ffn_compiles(one_chip):
